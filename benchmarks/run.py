@@ -36,6 +36,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.smoke and args.full:
         ap.error("--smoke and --full are mutually exclusive")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (fig2_scaling, fig9_quadrature, kernel_bench,
                             roofline_report, serving_bench,

@@ -4,7 +4,8 @@ state (no KV cache growth).
 
     PYTHONPATH=src python examples/serve.py                  # continuous
     PYTHONPATH=src python examples/serve.py --lockstep
-    PYTHONPATH=src python examples/serve.py --arch phi4-mini-3.8b --smoke
+    PYTHONPATH=src python examples/serve.py --arch phi4-mini-3.8b
+    PYTHONPATH=src python examples/serve.py --no-smoke    # published widths
 
 Sharded slot pool (DESIGN.md §8) — on CPU, force a multi-device runtime
 first (jax pins its device count at first init):
@@ -40,6 +41,7 @@ import numpy as np
 
 from repro import configs
 from repro.configs.base import ServingConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_serving_mesh
 from repro.models import api
 from repro.serving.engine import (AdmissionError, ContinuousServingEngine,
@@ -51,7 +53,10 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="slayformer-124m",
                     choices=list(configs.ALL_ARCHS))
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced widths (default); --no-smoke serves the "
+                         "arch at its published widths")
     ap.add_argument("--attn-kind", default=None)
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--batch", type=int, default=4)
@@ -91,13 +96,15 @@ def main():
     if args.speculative and args.lockstep:
         ap.error("--speculative needs the continuous engine")
 
+    enable_compile_cache()
     overrides = {"attn_kind": args.attn_kind} if args.attn_kind else {}
     if args.speculative and not args.attn_kind:
         # Exact verifier + a deliberately small SLAY draft trunk so the
         # demo's draft steps stay cheap on CPU.
         overrides = {"attn_kind": "yat_spherical",
                      "slay_anchors": 16, "slay_prf": 32}
-    cfg = configs.get_smoke_config(args.arch, **overrides)
+    get = configs.get_smoke_config if args.smoke else configs.get_config
+    cfg = get(args.arch, **overrides)
     params = api.init_params(cfg, jax.random.PRNGKey(0))
     # DESIGN §8 walkthrough, step 1 — the mesh: the `data` axis carries
     # slot parallelism at serving time. make_serving_mesh(N) takes the

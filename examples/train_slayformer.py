@@ -14,9 +14,12 @@ architectures (reduced smoke variant with --smoke).
 import argparse
 import dataclasses
 import logging
+import os
+import tempfile
 
 from repro import configs
 from repro.data.pipeline import DataConfig, batch_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.train.loop import TrainConfig, Trainer
@@ -52,11 +55,13 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-3)
-    ap.add_argument("--ckpt-dir", default="/tmp/slayformer_ckpt")
+    ap.add_argument("--ckpt-dir", default=os.path.join(
+        tempfile.gettempdir(), "slayformer_ckpt"))
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = build_config(args)
     print(f"arch={cfg.name} attn={cfg.attn_kind} layers={cfg.num_layers} "
           f"d_model={cfg.d_model} params~{cfg.param_count_dense / 1e6:.1f}M")

@@ -110,6 +110,8 @@ def record_pallas_calls(records: list, module_label: str):
             outs = _aslist(out_shape)
             in_bytes = 0
             for spec, arg in zip(_aslist(in_specs), args):
+                if spec.block_shape is None:    # whole array in SMEM
+                    continue
                 in_bytes += _nbytes(spec.block_shape, arg.dtype)
             out_bytes = 0
             for spec, sds in zip(_aslist(out_specs), outs):

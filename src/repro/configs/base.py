@@ -67,9 +67,10 @@ class ArchConfig:
     chunk_size: int = 256
     # Pallas attention kernels (trainable — the kernels carry custom VJPs).
     # use_pallas dispatches the compiled kernels on TPU (jnp reference
-    # elsewhere); fuse_attention_features selects the end-to-end megakernel
-    # over the two-dispatch feature→scan pipeline.
-    use_pallas: bool = False
+    # elsewhere — kernels.ops picks by platform, so the CPU tests run the
+    # reference); fuse_attention_features selects the end-to-end
+    # megakernel over the two-dispatch feature→scan pipeline.
+    use_pallas: bool = True
     fuse_attention_features: bool = True
     # Numerics
     dtype: str = "bfloat16"

@@ -92,7 +92,7 @@ class AttentionSpec:
     window: int = 0            # sliding window for local softmax layers
     logit_softcap: float = 0.0
     chunk_size: int = 256
-    use_pallas: bool = False
+    use_pallas: bool = True
     # With use_pallas: run the end-to-end megakernel (Ψ fused into the
     # attention scan, zero feature HBM traffic) instead of the two-dispatch
     # feature-kernel → scan-kernel pipeline.

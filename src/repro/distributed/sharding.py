@@ -112,6 +112,30 @@ def constrain(x, logical_axes: tuple):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+_SLOT_POOL_CTX: list = []    # stack of (mesh, slot axes)
+
+
+def in_slot_pool(fn, mesh: Mesh, axes: tuple[str, ...]):
+    """Wrap ``fn`` so that, while it runs (jit traces it by running it),
+    :func:`current_slot_pool` names the mesh and the axes the serving slot
+    pool shards over. A ``pallas_call`` is opaque to GSPMD, so a kernel on
+    the slot dim (``kernels.ops.decode_linear_step``) reads this to run
+    once per shard under ``shard_map`` instead of gathering the pool —
+    which keeps the decode step free of collectives (DESIGN.md §8)."""
+    def wrapped(*args, **kwargs):
+        _SLOT_POOL_CTX.append((mesh, tuple(axes)))
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _SLOT_POOL_CTX.pop()
+    return wrapped
+
+
+def current_slot_pool():
+    """(mesh, slot axes) of the pool being traced, or None outside one."""
+    return _SLOT_POOL_CTX[-1] if _SLOT_POOL_CTX else None
+
+
 def _mesh_axes_present(mesh: Mesh, axes) -> tuple[str, ...]:
     if axes is None:
         return ()

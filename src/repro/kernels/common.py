@@ -44,12 +44,25 @@ def tpu_params():
 
     The chunk axis must stay sequential ("arbitrary") so VMEM scratch
     carries state across grid steps; the head axis is embarrassingly
-    parallel. Handles the CompilerParams/TPUCompilerParams rename across
-    jax versions in one place.
+    parallel.
     """
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(dimension_semantics=("parallel", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+def col_to_row(x):
+    """(T, 1) -> (1, T) without arithmetic: lane-broadcast to (T, 128),
+    transpose the aligned tile, keep one row. Mosaic has no relayout for
+    a bare (T, 1) column, but transposes (8k, 128)-aligned 32-bit tiles.
+    """
+    t = x.shape[0]
+    return jnp.transpose(jnp.broadcast_to(x, (t, 128)))[:1, :]
+
+
+def row_to_col(x):
+    """(1, T) -> (T, 1); the inverse of :func:`col_to_row`."""
+    t = x.shape[1]
+    return jnp.transpose(jnp.broadcast_to(x, (128, t)))[:, :1]
 
 
 def features_fwd(u, a, w, st: FeatureStatics):
@@ -86,11 +99,19 @@ def features_bwd(dpsi, res, a, w, st: FeatureStatics):
     dphi_p = jnp.zeros_like(phi_p)                           # (T, P)
     dpw = jnp.zeros((t, D), jnp.float32)
     for r, (s, swr) in enumerate(zip(st.s_nodes, st.sqrt_w)):
-        m_r = dpsi[:, r * P * D:(r + 1) * P * D].reshape(t, P, D) * swr
         phi_e = phi_es[r]
-        # kron = phi_p ⊗ phi_e: split the cotangent.
-        dphi_p = dphi_p + jnp.einsum("tpd,td->tp", m_r, phi_e)
-        dphi_e = jnp.einsum("tpd,tp->td", m_r, phi_p)
+        # kron = phi_p ⊗ phi_e: split the cotangent. Lane p·D..(p+1)·D of
+        # the node's block holds anchor p; 2-D slices only (Mosaic has no
+        # in-kernel (T, P·D) -> (T, P, D) relayout).
+        base = r * P * D
+        m_p = [dpsi[:, base + p * D:base + (p + 1) * D] * swr
+               for p in range(P)]                            # P × (T, D)
+        dphi_p = dphi_p + jnp.concatenate(
+            [jnp.sum(mp * phi_e, axis=-1, keepdims=True) for mp in m_p],
+            axis=-1)                                         # (T, P)
+        dphi_e = m_p[0] * phi_p[:, 0:1]
+        for p in range(1, P):
+            dphi_e = dphi_e + m_p[p] * phi_p[:, p:p + 1]     # (T, D)
         # phi_e = exp(√(2s) pw − s)/√D → d pw = √(2s)·phi_e∘dphi_e.
         dpw = dpw + np.sqrt(2.0 * s) * phi_e * dphi_e
     dpa = 2.0 * pa * dphi_p * (1.0 / np.sqrt(P))             # (T, P)

@@ -29,18 +29,23 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import row_to_col
 
 
 def _step_body(qf_ref, kf_ref, v_ref, s_ref, z_ref, y_ref, s_out, z_out,
                delta: float):
     """Shared per-kv-head step: state RMW + grouped-query readout."""
-    kf = kf_ref[0].astype(jnp.float32)                       # (m,)
-    v = v_ref[0].astype(jnp.float32)                         # (dv,)
-    s = s_ref[0] + kf[:, None] * v[None, :]                  # (m, dv)
-    z = z_ref[0] + kf                                        # (m,)
+    kf = kf_ref[0].astype(jnp.float32)                       # (1, m)
+    v = v_ref[0].astype(jnp.float32)                         # (1, dv)
+    s = s_ref[0] + row_to_col(kf) * v                        # (m, dv)
+    z = z_ref[0] + kf                                        # (1, m)
     q = qf_ref[0].astype(jnp.float32)                        # (G, m)
     num = jax.lax.dot(q, s, preferred_element_type=jnp.float32)   # (G, dv)
-    den = q @ z[:, None]                                          # (G, 1)
+    # VPU row-dot: Mosaic refuses an MXU matvec whose result feeds `+ δ`
+    # (it folds δ into the accumulator, which must be zero).
+    den = jnp.sum(q * z, axis=-1, keepdims=True)                  # (G, 1)
     y_ref[0] = (num / (den + delta)).astype(y_ref.dtype)
     s_out[0] = s
     z_out[0] = z
@@ -48,8 +53,10 @@ def _step_body(qf_ref, kf_ref, v_ref, s_ref, z_ref, y_ref, s_out, z_out,
 
 def _kernel(qf_ref, kf_ref, v_ref, s_ref, z_ref, y_ref, s_out, z_out, *,
             delta: float):
-    """Refs (per kv head): qf (1, G, m), kf (1, m), v (1, dv),
-    s (1, m, dv) fp32, z (1, m) fp32; outs y (1, G, dv), s', z'."""
+    """Refs (per kv head): qf (1, G, m), kf (1, 1, m), v (1, 1, dv),
+    s (1, m, dv) fp32, z (1, 1, m) fp32; outs y (1, G, dv), s', z'. The
+    per-row vectors carry a unit middle axis so every block's last two
+    dims equal the array's (Mosaic's (8, 128) tiling rule)."""
     _step_body(qf_ref, kf_ref, v_ref, s_ref, z_ref, y_ref, s_out, z_out,
                delta)
 
@@ -58,12 +65,12 @@ def _kernel_masked(a_ref, qf_ref, kf_ref, v_ref, s_ref, z_ref, y_ref,
                    s_out, z_out, *, delta: float):
     """Active-slot-masked step for the continuous-batching pool.
 
-    a (1, 1) int32 per kv row: nonzero = slot is serving a live request.
-    Drained slots skip the feature/MXU work and the state RMW entirely —
-    the state block passes through unchanged and the output row is zero —
-    so an idle slot costs only the block pipeline, no compute.
+    a (BK,) int32 in SMEM, whole: nonzero = kv row i serves a live
+    request. Drained slots skip the feature/MXU work and the state RMW
+    entirely — the state block passes through unchanged and the output row
+    is zero — so an idle slot costs only the block pipeline, no compute.
     """
-    active = a_ref[0, 0] != 0
+    active = a_ref[pl.program_id(0)] != 0
 
     @pl.when(active)
     def _():
@@ -121,20 +128,20 @@ def _decode(st: DecodeStatics, qf, kf, v, s, z):
 def _specs(bk, g, m, dv, y_dtype):
     in_specs = [
         pl.BlockSpec((1, g, m), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, m), lambda i: (i, 0)),
-        pl.BlockSpec((1, dv), lambda i: (i, 0)),
+        pl.BlockSpec((1, 1, m), lambda i: (i, 0, 0)),
+        pl.BlockSpec((1, 1, dv), lambda i: (i, 0, 0)),
         pl.BlockSpec((1, m, dv), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, m), lambda i: (i, 0)),
+        pl.BlockSpec((1, 1, m), lambda i: (i, 0, 0)),
     ]
     out_specs = [
         pl.BlockSpec((1, g, dv), lambda i: (i, 0, 0)),
         pl.BlockSpec((1, m, dv), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, m), lambda i: (i, 0)),
+        pl.BlockSpec((1, 1, m), lambda i: (i, 0, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((bk, g, dv), y_dtype),
         jax.ShapeDtypeStruct((bk, m, dv), jnp.float32),
-        jax.ShapeDtypeStruct((bk, m), jnp.float32),
+        jax.ShapeDtypeStruct((bk, 1, m), jnp.float32),
     ]
     return in_specs, out_specs, out_shape
 
@@ -154,8 +161,8 @@ def _decode_impl(st: DecodeStatics, qf, kf, v, s, z):
         out_shape=out_shape,
         input_output_aliases={3: 1, 4: 2},   # s, z updated in place
         interpret=st.interpret,
-    )(qg, kf, v, s, z)
-    return y.reshape(bh, dv), s2, z2
+    )(qg, kf[:, None], v[:, None], s, z[:, None])
+    return y.reshape(bh, dv), s2, z2.reshape(bk, m)
 
 
 def _decode_masked(st: DecodeStatics, qf, kf, v, s, z, active):
@@ -164,7 +171,7 @@ def _decode_masked(st: DecodeStatics, qf, kf, v, s, z, active):
     g = bh // bk
     qg = qf.reshape(bk, g, m)
     in_specs, out_specs, out_shape = _specs(bk, g, m, dv, v.dtype)
-    in_specs = [pl.BlockSpec((1, 1), lambda i: (i, 0))] + in_specs
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + in_specs
 
     y, s2, z2 = pl.pallas_call(
         functools.partial(_kernel_masked, delta=st.delta),
@@ -174,8 +181,8 @@ def _decode_masked(st: DecodeStatics, qf, kf, v, s, z, active):
         out_shape=out_shape,
         input_output_aliases={4: 1, 5: 2},   # s, z updated in place
         interpret=st.interpret,
-    )(active.reshape(bk, 1), qg, kf, v, s, z)
-    return y.reshape(bh, dv), s2, z2
+    )(active, qg, kf[:, None], v[:, None], s, z[:, None])
+    return y.reshape(bh, dv), s2, z2.reshape(bk, m)
 
 
 def _decode_fwd(st: DecodeStatics, qf, kf, v, s, z):

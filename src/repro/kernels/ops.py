@@ -3,21 +3,24 @@
 Model code calls these; they translate between the model's
 (..., L, H, feat) layout and the kernels' head-major (BH, L, feat) layout,
 zero-pad ragged lengths to block multiples (zero features contribute
-nothing to the running state, matching ``core.linear_attention``), and fall
-back to the jnp reference on non-TPU backends.
+nothing to the running state, matching ``core.linear_attention``), and run
+the jnp reference on non-TPU backends.
 
 ``interpret`` semantics (uniform across wrappers):
-    None  — compiled kernel on TPU, jnp reference elsewhere (production).
-    False — same as None: "compiled kernel if available"; an explicit False
-            never forces an interpret-mode kernel onto CPU.
-    True  — interpret-mode kernel on any backend (correctness tests).
+    None / False — compiled kernel on TPU, jnp reference elsewhere. The
+            platform picks the path; the reference is the CPU path, not a
+            fallback for a TPU kernel that failed.
+    True  — interpret-mode kernel, for the CPU parity tests. Refused on a
+            TPU: the chip path only ever runs compiled kernels.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.features import SlayFeatureConfig
+from repro.distributed import sharding as shd
 from repro.kernels import decode_step as _dk
 from repro.kernels import feature_map as _fm
 from repro.kernels import ref as _ref
@@ -30,8 +33,11 @@ def _on_tpu() -> bool:
 
 
 def _use_kernel(interpret: bool | None) -> bool:
-    """Kernel-vs-reference dispatch for the ``interpret`` tri-state."""
-    if interpret is True:
+    """Kernel-vs-reference dispatch for the ``interpret`` flag."""
+    if interpret:
+        if _on_tpu():
+            raise ValueError("interpret=True on a TPU: the chip path runs "
+                             "compiled kernels only")
         return True
     return _on_tpu()
 
@@ -105,8 +111,8 @@ def slay_fused_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     per-token HBM traffic is the raw O(L·d) q/k/v reads and the O(L·dv)
     output write. Differentiable (custom VJP); ragged L is zero-padded.
 
-    Falls back to the jnp reference (features + chunked scan) off-TPU or
-    for non-kernelizable feature configs.
+    Runs the jnp reference (features + chunked scan) off-TPU and for
+    non-kernelizable feature configs.
     """
     kernelizable = (cfg.poly_kind == "anchor" and cfg.fusion == "tensor")
     if not (_use_kernel(interpret) and kernelizable):
@@ -138,8 +144,28 @@ def decode_linear_step(qf: jnp.ndarray, kf: jnp.ndarray, v: jnp.ndarray,
     RMW). ``active`` (B,) masks continuous-batching pool rows — drained
     slots skip the state update and MXU readout (y rows zero, (s, z) pass
     through bit-identical), so an idle slot costs only block pipelining.
-    Falls back to the jnp oracle off-TPU with identical masked semantics.
+    Runs the jnp oracle off-TPU, with identical masked semantics.
+
+    Inside a slot-sharded serving pool (``sharding.current_slot_pool``)
+    the kernel runs once per shard under ``shard_map`` over the slot dim:
+    GSPMD cannot partition a ``pallas_call`` and would otherwise gather
+    the whole pool onto every device.
     """
+    pool = shd.current_slot_pool()
+    if _use_kernel(interpret) and pool is not None and pool[1]:
+        mesh, axes = pool
+        spec = P(axes[0] if len(axes) == 1 else axes)
+        args = (qf, kf, v, s, z) + (() if active is None else (active,))
+
+        def local(*a):
+            return _decode_linear_step(*a, delta=delta, interpret=interpret)
+        return jax.shard_map(local, mesh=mesh, in_specs=(spec,) * len(args),
+                             out_specs=(spec,) * 3, check_vma=False)(*args)
+    return _decode_linear_step(qf, kf, v, s, z, active, delta=delta,
+                               interpret=interpret)
+
+
+def _decode_linear_step(qf, kf, v, s, z, active=None, *, delta, interpret):
     B, H, m = qf.shape
     hkv, dv = kf.shape[-2], v.shape[-1]
     g = H // hkv

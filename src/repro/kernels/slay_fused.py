@@ -49,8 +49,8 @@ from jax.experimental import pallas as pl
 from repro.core import quadrature
 from repro.core.features import SlayFeatureConfig
 from repro.kernels.common import (FeatureStatics, causal_mask as _causal_mask,
-                                  features_bwd, features_fwd,
-                                  tpu_params as _tpu_params,
+                                  col_to_row, features_bwd, features_fwd,
+                                  row_to_col, tpu_params as _tpu_params,
                                   vmem_scratch as _scratch)
 
 
@@ -89,7 +89,7 @@ def statics_for(cfg: SlayFeatureConfig, *, chunk_size: int, delta: float,
 def _fwd_kernel(q_ref, k_ref, v_ref, a_ref, w_ref, o_ref, den_ref,
                 s_ref, z_ref, *, st: FusedStatics):
     """Blocks: q (1,T,d), k (1,T,d), v (1,T,dv), a (P,d), w (D,d);
-    outs o (1,T,dv), den (1,T); scratch s (m,dv) fp32, z (1,m) fp32."""
+    outs o (1,T,dv), den (1,1,T); scratch s (m,dv) fp32, z (1,m) fp32."""
     c = pl.program_id(1)
 
     @pl.when(c == 0)
@@ -103,10 +103,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, a_ref, w_ref, o_ref, den_ref,
     kf, _ = features_fwd(k_ref[0].astype(jnp.float32), a, w, st.feat)   # (T, m)
     v = v_ref[0].astype(jnp.float32)                                # (T, dv)
     s = s_ref[...]
-    z = z_ref[0]
+    z = z_ref[...]                                                  # (1, m)
 
     num = jax.lax.dot(qf, s, preferred_element_type=jnp.float32)    # (T, dv)
-    den = qf @ z[:, None]                                           # (T, 1)
+    den = jax.lax.dot_general(qf, z, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)   # (T, 1)
     scores = _causal_mask(jax.lax.dot_general(
         qf, kf, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32))                        # (T, T)
@@ -114,11 +115,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, a_ref, w_ref, o_ref, den_ref,
     den = den + jnp.sum(scores, axis=1, keepdims=True)
 
     o_ref[0] = (num / (den + st.delta)).astype(o_ref.dtype)
-    den_ref[0] = den[:, 0]
+    den_ref[0] = col_to_row(den)
 
     s_ref[...] = s + jax.lax.dot_general(kf, v, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
-    z_ref[0] = z + jnp.sum(kf, axis=0)
+    z_ref[...] = z + jnp.sum(kf, axis=0, keepdims=True)
 
 
 def _fwd_impl(st: FusedStatics, q, k, v, anchors, omegas):
@@ -141,11 +142,11 @@ def _fwd_impl(st: FusedStatics, q, k, v, anchors, omegas):
         ],
         out_specs=[
             pl.BlockSpec((1, t, dv), lambda h, c: (h, c, 0)),
-            pl.BlockSpec((1, t), lambda h, c: (h, c)),
+            pl.BlockSpec((1, 1, t), lambda h, c: (h, 0, c)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, L, dv), v.dtype),
-            jax.ShapeDtypeStruct((bh, L), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, L), jnp.float32),
         ],
         scratch_shapes=[_scratch((m, dv)), _scratch((1, m))],
         compiler_params=_tpu_params(),
@@ -176,9 +177,9 @@ def _bwd_q_kernel(q_ref, k_ref, v_ref, a_ref, w_ref, dy_ref, y_ref, den_ref,
     v = v_ref[0].astype(jnp.float32)
     dy = dy_ref[0].astype(jnp.float32)                        # (T, dv)
     y = y_ref[0].astype(jnp.float32)                          # (T, dv)
-    e = den_ref[0][:, None] + st.delta                        # (T, 1)
+    e = row_to_col(den_ref[0]) + st.delta                     # (T, 1)
     s = s_ref[...]
-    z = z_ref[0]
+    z = z_ref[...]                                            # (1, m)
 
     gg = dy / e                                               # dnum (T, dv)
     hh = -jnp.sum(dy * y, axis=-1, keepdims=True) / e         # dden (T, 1)
@@ -188,7 +189,7 @@ def _bwd_q_kernel(q_ref, k_ref, v_ref, a_ref, w_ref, dy_ref, y_ref, den_ref,
                             preferred_element_type=jnp.float32) + hh)
     dqf = (jax.lax.dot_general(gg, s, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
-           + hh * z[None, :]
+           + hh * z
            + jax.lax.dot(dp, kf, preferred_element_type=jnp.float32))
     dq, da, dw = features_bwd(dqf, qres, a, w, st.feat)
     dq_ref[0] = dq.astype(dq_ref.dtype)
@@ -197,7 +198,7 @@ def _bwd_q_kernel(q_ref, k_ref, v_ref, a_ref, w_ref, dy_ref, y_ref, den_ref,
 
     s_ref[...] = s + jax.lax.dot_general(kf, v, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
-    z_ref[0] = z + jnp.sum(kf, axis=0)
+    z_ref[...] = z + jnp.sum(kf, axis=0, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
@@ -224,9 +225,9 @@ def _bwd_kv_kernel(q_ref, k_ref, v_ref, a_ref, w_ref, dy_ref, y_ref, den_ref,
     v = v_ref[0].astype(jnp.float32)
     dy = dy_ref[0].astype(jnp.float32)
     y = y_ref[0].astype(jnp.float32)
-    e = den_ref[0][:, None] + st.delta
+    e = row_to_col(den_ref[0]) + st.delta                     # (T, 1)
     ds = ds_ref[...]                                          # (m, dv)
-    dz = dz_ref[0]                                            # (m,)
+    dz = dz_ref[...]                                          # (1, m)
 
     gg = dy / e
     hh = -jnp.sum(dy * y, axis=-1, keepdims=True) / e
@@ -241,7 +242,7 @@ def _bwd_kv_kernel(q_ref, k_ref, v_ref, a_ref, w_ref, dy_ref, y_ref, den_ref,
                                preferred_element_type=jnp.float32)
            + jax.lax.dot_general(v, ds, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-           + dz[None, :])
+           + dz)
     dvv = (jax.lax.dot_general(scores, gg, (((0,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
            + jax.lax.dot(kf, ds, preferred_element_type=jnp.float32))
@@ -255,7 +256,7 @@ def _bwd_kv_kernel(q_ref, k_ref, v_ref, a_ref, w_ref, dy_ref, y_ref, den_ref,
     ds_ref[...] = ds + jax.lax.dot_general(
         qf, gg, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    dz_ref[0] = dz + jnp.sum(qf * hh, axis=0)
+    dz_ref[...] = dz + jnp.sum(qf * hh, axis=0, keepdims=True)
 
 
 def _bwd_impl(st: FusedStatics, q, k, v, anchors, omegas, y, den, dy):
@@ -275,7 +276,7 @@ def _bwd_impl(st: FusedStatics, q, k, v, anchors, omegas, y, den, dy):
         pl.BlockSpec((D, d), lambda h, c: (0, 0)),
         pl.BlockSpec((1, t, dv), lambda h, c: (h, c, 0)),   # dy
         pl.BlockSpec((1, t, dv), lambda h, c: (h, c, 0)),   # y
-        pl.BlockSpec((1, t), lambda h, c: (h, c)),          # den
+        pl.BlockSpec((1, 1, t), lambda h, c: (h, 0, c)),    # den
     ]
     dq, da_q, dw_q = pl.pallas_call(
         functools.partial(_bwd_q_kernel, st=st),
@@ -305,7 +306,7 @@ def _bwd_impl(st: FusedStatics, q, k, v, anchors, omegas, y, den, dy):
         pl.BlockSpec((D, d), lambda h, c: (0, 0)),
         pl.BlockSpec((1, t, dv), lambda h, c: (h, nc - 1 - c, 0)),
         pl.BlockSpec((1, t, dv), lambda h, c: (h, nc - 1 - c, 0)),
-        pl.BlockSpec((1, t), lambda h, c: (h, nc - 1 - c)),
+        pl.BlockSpec((1, 1, t), lambda h, c: (h, 0, nc - 1 - c)),
     ]
     dk_p, dv_p, da_k, dw_k = pl.pallas_call(
         functools.partial(_bwd_kv_kernel, st=st),

@@ -38,8 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import (causal_mask as _causal_mask,
-                                  tpu_params as _tpu_params,
+from repro.kernels.common import (causal_mask as _causal_mask, col_to_row,
+                                  row_to_col, tpu_params as _tpu_params,
                                   vmem_scratch as _scratch)
 
 
@@ -54,7 +54,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, den_ref, s_ref, z_ref, *,
     """One (head, chunk) grid step. Refs hold VMEM blocks:
 
     q_ref (1, T, m), k_ref (1, T, m), v_ref (1, T, dv); outs o (1, T, dv),
-    den (1, T); scratch s_ref (m, dv) fp32, z_ref (1, m) fp32.
+    den (1, 1, T) (lane-dense row); scratch s_ref (m, dv) fp32, z_ref
+    (1, m) fp32.
     """
     c = pl.program_id(1)
 
@@ -67,11 +68,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, den_ref, s_ref, z_ref, *,
     k = k_ref[0].astype(jnp.float32)          # (T, m)
     v = v_ref[0].astype(jnp.float32)          # (T, dv)
     s = s_ref[...]                            # (m, dv)
-    z = z_ref[0]                              # (m,)
+    z = z_ref[...]                            # (1, m)
 
     # Inter-chunk: prefix state contribution.
     num = jax.lax.dot(q, s, preferred_element_type=jnp.float32)      # (T, dv)
-    den = q @ z[:, None]                                             # (T, 1)
+    den = jax.lax.dot_general(q, z, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)    # (T, 1)
 
     # Intra-chunk: causal quadratic on features (T×T stays in VMEM).
     scores = _causal_mask(jax.lax.dot_general(
@@ -81,12 +83,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, den_ref, s_ref, z_ref, *,
     den = den + jnp.sum(scores, axis=1, keepdims=True)
 
     o_ref[0] = (num / (den + delta)).astype(o_ref.dtype)
-    den_ref[0] = den[:, 0]
+    den_ref[0] = col_to_row(den)
 
     # Carry the running state to the next chunk.
     s_ref[...] = s + jax.lax.dot_general(k, v, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
-    z_ref[0] = z + jnp.sum(k, axis=0)
+    z_ref[...] = z + jnp.sum(k, axis=0, keepdims=True)
 
 
 def _fwd_impl(st: ScanStatics, qf, kf, v):
@@ -105,11 +107,11 @@ def _fwd_impl(st: ScanStatics, qf, kf, v):
         ],
         out_specs=[
             pl.BlockSpec((1, t, dv), lambda h, c: (h, c, 0)),
-            pl.BlockSpec((1, t), lambda h, c: (h, c)),
+            pl.BlockSpec((1, 1, t), lambda h, c: (h, 0, c)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, L, dv), v.dtype),
-            jax.ShapeDtypeStruct((bh, L), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, L), jnp.float32),
         ],
         scratch_shapes=[
             _scratch((m, dv)),   # S: running ΣKᵀV
@@ -140,9 +142,9 @@ def _bwd_q_kernel(q_ref, k_ref, v_ref, dy_ref, y_ref, den_ref, dq_ref,
     v = v_ref[0].astype(jnp.float32)
     dy = dy_ref[0].astype(jnp.float32)
     y = y_ref[0].astype(jnp.float32)
-    e = den_ref[0][:, None] + delta
+    e = row_to_col(den_ref[0]) + delta                # (T, 1)
     s = s_ref[...]
-    z = z_ref[0]
+    z = z_ref[...]                                    # (1, m)
 
     gg = dy / e
     hh = -jnp.sum(dy * y, axis=-1, keepdims=True) / e
@@ -151,13 +153,13 @@ def _bwd_q_kernel(q_ref, k_ref, v_ref, dy_ref, y_ref, den_ref, dq_ref,
                             preferred_element_type=jnp.float32) + hh)
     dq = (jax.lax.dot_general(gg, s, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
-          + hh * z[None, :]
+          + hh * z
           + jax.lax.dot(dp, k, preferred_element_type=jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
     s_ref[...] = s + jax.lax.dot_general(k, v, (((0,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
-    z_ref[0] = z + jnp.sum(k, axis=0)
+    z_ref[...] = z + jnp.sum(k, axis=0, keepdims=True)
 
 
 def _bwd_kv_kernel(q_ref, k_ref, v_ref, dy_ref, y_ref, den_ref, dk_ref,
@@ -176,9 +178,9 @@ def _bwd_kv_kernel(q_ref, k_ref, v_ref, dy_ref, y_ref, den_ref, dk_ref,
     v = v_ref[0].astype(jnp.float32)
     dy = dy_ref[0].astype(jnp.float32)
     y = y_ref[0].astype(jnp.float32)
-    e = den_ref[0][:, None] + delta
+    e = row_to_col(den_ref[0]) + delta                # (T, 1)
     ds = ds_ref[...]
-    dz = dz_ref[0]
+    dz = dz_ref[...]                                  # (1, m)
 
     gg = dy / e
     hh = -jnp.sum(dy * y, axis=-1, keepdims=True) / e
@@ -192,7 +194,7 @@ def _bwd_kv_kernel(q_ref, k_ref, v_ref, dy_ref, y_ref, den_ref, dk_ref,
                               preferred_element_type=jnp.float32)
           + jax.lax.dot_general(v, ds, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-          + dz[None, :])
+          + dz)
     dvv = (jax.lax.dot_general(scores, gg, (((0,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
            + jax.lax.dot(k, ds, preferred_element_type=jnp.float32))
@@ -202,7 +204,7 @@ def _bwd_kv_kernel(q_ref, k_ref, v_ref, dy_ref, y_ref, den_ref, dk_ref,
     ds_ref[...] = ds + jax.lax.dot_general(
         q, gg, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    dz_ref[0] = dz + jnp.sum(q * hh, axis=0)
+    dz_ref[...] = dz + jnp.sum(q * hh, axis=0, keepdims=True)
 
 
 def _bwd_impl(st: ScanStatics, qf, kf, v, y, den, dy):
@@ -221,7 +223,7 @@ def _bwd_impl(st: ScanStatics, qf, kf, v, y, den, dy):
             pl.BlockSpec((1, t, dv), lambda h, c: (h // g, c, 0)),
             pl.BlockSpec((1, t, dv), lambda h, c: (h, c, 0)),
             pl.BlockSpec((1, t, dv), lambda h, c: (h, c, 0)),
-            pl.BlockSpec((1, t), lambda h, c: (h, c)),
+            pl.BlockSpec((1, 1, t), lambda h, c: (h, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, t, m), lambda h, c: (h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, L, m), qf.dtype),
@@ -239,7 +241,7 @@ def _bwd_impl(st: ScanStatics, qf, kf, v, y, den, dy):
             pl.BlockSpec((1, t, dv), lambda h, c: (h // g, nc - 1 - c, 0)),
             pl.BlockSpec((1, t, dv), lambda h, c: (h, nc - 1 - c, 0)),
             pl.BlockSpec((1, t, dv), lambda h, c: (h, nc - 1 - c, 0)),
-            pl.BlockSpec((1, t), lambda h, c: (h, nc - 1 - c)),
+            pl.BlockSpec((1, 1, t), lambda h, c: (h, 0, nc - 1 - c)),
         ],
         out_specs=[
             pl.BlockSpec((1, t, m), lambda h, c: (h, nc - 1 - c, 0)),

@@ -27,7 +27,7 @@ from repro import configs
 from repro.configs.base import ArchConfig, ShapeCell
 from repro.distributed import sharding as shd
 from repro.launch import roofline as rl
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models import api
 from repro.optim.adamw import AdamWConfig, adamw_init
 from repro.train.loop import TrainConfig, make_train_step
@@ -148,7 +148,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False,
         # Same 256-chip pod (or 512-chip 2-pod), different logical split —
         # e.g. (32, 8) so a 24-head/8-kv arch shards instead of replicating.
         axes = ("pod", "data", "model")[-len(mesh_shape):]
-        mesh = jax.make_mesh(mesh_shape, axes)
+        mesh = make_mesh(mesh_shape, axes)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size

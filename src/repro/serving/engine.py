@@ -762,7 +762,7 @@ class ContinuousServingEngine:
         # num_slots is not divisible (recorded like the rule-engine
         # divisibility fallback; surfaced in metrics/bench rows).
         self.slot_shard_fallbacks: list = []
-        _, self.slot_shards = shd.pool_slot_axes(
+        slot_axes, self.slot_shards = shd.pool_slot_axes(
             mesh, rules, S, serving.slot_shards,
             self.slot_shard_fallbacks)
         self.sched = Scheduler(serving, self.slot_shards)
@@ -900,12 +900,16 @@ class ContinuousServingEngine:
         # pool (donated cache, fused sampling, masked drained slots). Every
         # input/output carries the slot sharding, so the scan partitions
         # into independent per-shard slot blocks — no collectives (§8).
+        # in_slot_pool lets the decode kernel, which GSPMD cannot
+        # partition, run per shard under shard_map.
         self._macro_fn = jax.jit(
-            functools.partial(_macro_decode, cfg=cfg,
-                              num_ticks=serving.macro_ticks,
-                              temperature=serving.temperature,
-                              seed=serving.seed,
-                              fault_guard=serving.fault_guard),
+            shd.in_slot_pool(
+                functools.partial(_macro_decode, cfg=cfg,
+                                  num_ticks=serving.macro_ticks,
+                                  temperature=serving.temperature,
+                                  seed=serving.seed,
+                                  fault_guard=serving.fault_guard),
+                mesh, slot_axes),
             in_shardings=(p_sh, c_sh) + (v_sh,) * 6,
             out_shardings=(c_sh, buf_sh, buf_sh, buf_sh),
             donate_argnums=(1,))
@@ -929,13 +933,15 @@ class ContinuousServingEngine:
                 self.draft_pool = jax.device_put(
                     api.init_cache(self.draft_cfg, S, L), d_sh)
             self._spec_fn = jax.jit(
-                functools.partial(speculative.spec_macro,
-                                  draft_cfg=self.draft_cfg, cfg=cfg,
-                                  num_rounds=serving.macro_ticks,
-                                  gamma=serving.spec_gamma,
-                                  temperature=serving.temperature,
-                                  seed=serving.seed,
-                                  fault_guard=serving.fault_guard),
+                shd.in_slot_pool(
+                    functools.partial(speculative.spec_macro,
+                                      draft_cfg=self.draft_cfg, cfg=cfg,
+                                      num_rounds=serving.macro_ticks,
+                                      gamma=serving.spec_gamma,
+                                      temperature=serving.temperature,
+                                      seed=serving.seed,
+                                      fault_guard=serving.fault_guard),
+                    mesh, slot_axes),
                 in_shardings=(p_sh, d_sh, c_sh) + (v_sh,) * 6,
                 out_shardings=(d_sh, c_sh, buf2_sh, buf2_sh, buf2_sh,
                                buf_sh),

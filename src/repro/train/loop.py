@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import os
+import tempfile
 import time
 
 import jax
@@ -58,7 +60,7 @@ class TrainConfig:
     # skips re-running the forward TP collectives (§Perf).
     remat_policy: str = "nothing"
     compress_grads: bool = False     # error-feedback int8 (DP payload /4)
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str = os.path.join(tempfile.gettempdir(), "repro_ckpt")
     ckpt_every: int = 200
     watchdog_factor: float = 2.0     # step slower than factor x median -> flag
     keep_ckpts: int = 3

@@ -221,9 +221,47 @@ def check_paged():
           f"({nops} ops)")
 
 
+def check_kernel():
+    """The Pallas decode kernel on a slot-sharded pool: it runs once per
+    shard under shard_map (GSPMD cannot partition a pallas_call), streams
+    stay byte-identical to the one-device pool, and the decode step keeps
+    zero collectives. On a TPU the kernel path is picked by platform; here
+    the dispatch is forced to the kernel, run in interpret mode."""
+    from repro.kernels import decode_step as dk
+    from repro.kernels import ops
+
+    rows = []
+    real, real_use = dk.decode_linear_attention, ops._use_kernel
+
+    def interpret_kernel(*args, **kwargs):
+        rows.append(args[0].shape[0])
+        return real(*args, **{**kwargs, "interpret": True})
+
+    ops._use_kernel = lambda interpret: True
+    dk.decode_linear_attention = interpret_kernel
+    try:
+        cfg, params = _setup("slay")
+        reqs = _poisson_trace(cfg)
+        _, o1, _ = _run(cfg, params, data=1, num_slots=4, macro_ticks=8,
+                        reqs=reqs)
+        e4, o4, s4 = _run(cfg, params, data=4, num_slots=4, macro_ticks=8,
+                          reqs=reqs)
+        assert s4["slot_shards"] == 4
+        for rid in o1:
+            np.testing.assert_array_equal(o1[rid], o4[rid])
+        nops = _assert_collective_free(e4.decode_hlo(), "decode_hlo[kernel]")
+    finally:
+        dk.decode_linear_attention, ops._use_kernel = real, real_use
+    # One kv row per (slot, head): 4 slots whole, 1 slot per shard.
+    heads = cfg.num_kv_heads
+    assert set(rows) == {4 * heads, heads}, rows
+    print(f"kernel OK: per-shard decode kernel, streams byte-identical, "
+          f"no collectives ({nops} ops)")
+
+
 CHECKS = {"parity": check_parity, "evict_reuse": check_evict_reuse,
           "fallback": check_fallback, "collectives": check_collectives,
-          "paged": check_paged}
+          "paged": check_paged, "kernel": check_kernel}
 
 
 def main():

@@ -124,10 +124,12 @@ def test_fixture_hlo_host_callback():
 
 def test_dropped_donation_is_caught():
     """DON001 end to end on real compiled output: donate an input that
-    cannot alias any output (f32 in, i32 out) — XLA silently drops the
-    donation, and the analyzer must say so."""
+    cannot alias any output (an (8,) f32 in, a scalar out: no output has
+    its byte size) — XLA silently drops the donation, and the analyzer
+    must say so. (Same-size outputs do not work as a control: XLA aliases
+    an f32[8] input to an s32[8] output.)"""
     def bad(x):
-        return (x > 0).astype(jnp.int32)
+        return jnp.sum(x)
 
     lowered = jax.jit(bad, donate_argnums=(0,)).lower(
         jax.ShapeDtypeStruct((8,), jnp.float32))

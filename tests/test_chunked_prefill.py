@@ -88,8 +88,13 @@ def test_ssd_prefill_chunk_schedule_invariant(key, schedule):
                                       **kw)
     np.testing.assert_allclose(np.asarray(st.h), np.asarray(st_one.h),
                                atol=1e-5, rtol=1e-4)
-    np.testing.assert_array_equal(np.asarray(st.conv),
-                                  np.asarray(st_one.conv))
+    # The conv tail is the last W-1 rows of the in_proj matmul. XLA's CPU
+    # dot blocks by the row count, so a row projected in a 17-row chunk
+    # and in a 529-row one-shot call can round differently in its
+    # 16-term fp32 sum (measured: 1.2e-7, one ulp at |u| < 1). Everything
+    # downstream of the tail is exact; a few ulps are allowed here.
+    np.testing.assert_allclose(np.asarray(st.conv), np.asarray(st_one.conv),
+                               atol=1e-6, rtol=1e-6)
 
 
 # ---------------------------------------------------------------------------

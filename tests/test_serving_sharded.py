@@ -170,3 +170,11 @@ def test_sharded_decode_has_no_collectives():
     """The compiled decode macro-step on mesh=(data=4,) contains no
     cross-shard collectives (the §8 hot-loop contract), both regimes."""
     _run_driver("collectives")
+
+
+@pytest.mark.serving
+def test_sharded_decode_kernel_runs_per_shard():
+    """With the Pallas decode kernel on, a slot-sharded pool runs it once
+    per shard (shard_map): streams match the one-device pool and the
+    decode step stays collective-free."""
+    _run_driver("kernel")

@@ -8,7 +8,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_host_mesh, make_mesh
 
 
 @pytest.fixture(scope="module")
@@ -27,7 +27,7 @@ def test_partition_spec_divisibility_fallback():
     and log the fallback."""
     import os
     rules = shd.ShardingRules()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     log = []
     # mesh axes are size 1 -> everything divisible; simulate via rule lookup
     spec = shd.partition_spec(mesh, rules, (7, 3), ("embed", "kv_heads"), log)
