@@ -1,0 +1,7 @@
+"""Per-layer metric `mfu.decode`: the whole decode step's share of the chip's
+peak FLOP/s; see `bench.readers.mfu_decode`."""
+from bench import readers
+
+
+def read(ctx):
+    return readers.mfu_decode(ctx)
