@@ -25,6 +25,7 @@ Rules are data (a dataclass), so perf iterations can swap whole schemes
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import numpy as np
@@ -121,7 +122,9 @@ def in_slot_pool(fn, mesh: Mesh, axes: tuple[str, ...]):
     pool shards over. A ``pallas_call`` is opaque to GSPMD, so a kernel on
     the slot dim (``kernels.ops.decode_linear_step``) reads this to run
     once per shard under ``shard_map`` instead of gathering the pool —
-    which keeps the decode step free of collectives (DESIGN.md §8)."""
+    which keeps the decode step free of collectives (DESIGN.md §8).
+    The wrapper carries ``fn``'s name, so the jitted program keeps it."""
+    @functools.wraps(fn)
     def wrapped(*args, **kwargs):
         _SLOT_POOL_CTX.append((mesh, tuple(axes)))
         try:

@@ -33,6 +33,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import row_to_col
 
+# The name the device trace gives the kernel's op (plain and masked): a
+# profile reader finds the decode step by it.
+KERNEL_NAME = "decode_linear_attention"
+
 
 def _step_body(qf_ref, kf_ref, v_ref, s_ref, z_ref, y_ref, s_out, z_out,
                delta: float):
@@ -156,6 +160,7 @@ def _decode_impl(st: DecodeStatics, qf, kf, v, s, z):
     y, s2, z2 = pl.pallas_call(
         functools.partial(_kernel, delta=st.delta),
         grid=(bk,),
+        name=KERNEL_NAME,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -176,6 +181,7 @@ def _decode_masked(st: DecodeStatics, qf, kf, v, s, z, active):
     y, s2, z2 = pl.pallas_call(
         functools.partial(_kernel_masked, delta=st.delta),
         grid=(bk,),
+        name=KERNEL_NAME,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,

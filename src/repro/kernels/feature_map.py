@@ -90,6 +90,7 @@ def _fwd_impl(st: _MapStatics, u, anchors, omegas):
     return pl.pallas_call(
         functools.partial(_kernel, feat=f),
         grid=(n // block,),
+        name="slay_feature_map",
         in_specs=[
             pl.BlockSpec((block, d), lambda i: (i, 0)),
             pl.BlockSpec((f.num_anchors, d), lambda i: (0, 0)),

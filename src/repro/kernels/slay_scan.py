@@ -100,6 +100,7 @@ def _fwd_impl(st: ScanStatics, qf, kf, v):
     return pl.pallas_call(
         functools.partial(_kernel, delta=st.delta),
         grid=grid,
+        name="slay_scan",
         in_specs=[
             pl.BlockSpec((1, t, m), lambda h, c: (h, c, 0)),
             pl.BlockSpec((1, t, m), lambda h, c: (h // g, c, 0)),

@@ -40,12 +40,21 @@ lifecycle (queued, mid-prefill, slot-resident, even mid-macro-step); a
 per-slot NaN/Inf lane inside the jitted macro-step detects numeric faults
 and the host replay quarantines + retries them. ``serving.faults`` holds
 the deterministic chaos injector that exercises all of it.
+
+Tracing: the continuous engine writes host spans (``engine.*``, via
+``jax.profiler.TraceAnnotation``) at step granularity — the step, the
+admission, each prefill chunk, the first-token pull and the slot install,
+the decode launch / device wait / host replay, the journal flush and the
+checkpoint — never per token or per slot. With no profiler running a span
+costs well under a microsecond; under ``jax.profiler.trace`` they land on
+the device trace's clock. Every jitted engine program is a named function
+(``engine_macro_decode``, ``engine_prefill_chunk``, ...), so the device
+trace lists it as ``jit_<name>``.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
-import functools
 import os
 import time
 from typing import Callable
@@ -53,6 +62,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig, ServingConfig
 from repro.distributed import sharding as shd
@@ -329,7 +339,8 @@ class RequestStats:
     arrival: float                   # ticks
     prompt_len: int = 0
     slot: int | None = None          # pool slot served in (last, if retried)
-    admitted: float | None = None    # prefill started
+    admitted: float | None = None    # prefill started (ticks)
+    admitted_wall: float | None = None  # prefill started (engine clock)
     first_token: float | None = None
     finished: float | None = None
     first_token_wall: float | None = None
@@ -384,6 +395,10 @@ class ServingMetrics:
     # pulls are tracked separately (prefill_token_syncs): they are one
     # int32 scalar per admitted request, off the per-token hot loop.
     decode_dispatches: int = 0  # jitted K-tick macro-step calls (count)
+    # Macro-steps dispatched while a request stood ready and a slot was
+    # free: the admissions the interleave policy (decode_ticks_per_prefill)
+    # held back behind a decode dispatch.
+    decode_dispatches_while_ready: int = 0  # (count)
     host_syncs: int = 0         # blocking device->host pulls, decode (count)
     prefill_token_syncs: int = 0  # first-token scalar pulls at admit (count)
     bucket_hits: int = 0        # fallback prefill reusing a bucket (count)
@@ -902,14 +917,15 @@ class ContinuousServingEngine:
         # into independent per-shard slot blocks — no collectives (§8).
         # in_slot_pool lets the decode kernel, which GSPMD cannot
         # partition, run per shard under shard_map.
+        def engine_macro_decode(params, cache, *vectors):
+            return _macro_decode(params, cache, *vectors, cfg=cfg,
+                                 num_ticks=serving.macro_ticks,
+                                 temperature=serving.temperature,
+                                 seed=serving.seed,
+                                 fault_guard=serving.fault_guard)
+
         self._macro_fn = jax.jit(
-            shd.in_slot_pool(
-                functools.partial(_macro_decode, cfg=cfg,
-                                  num_ticks=serving.macro_ticks,
-                                  temperature=serving.temperature,
-                                  seed=serving.seed,
-                                  fault_guard=serving.fault_guard),
-                mesh, slot_axes),
+            shd.in_slot_pool(engine_macro_decode, mesh, slot_axes),
             in_shardings=(p_sh, c_sh) + (v_sh,) * 6,
             out_shardings=(c_sh, buf_sh, buf_sh, buf_sh),
             donate_argnums=(1,))
@@ -932,24 +948,28 @@ class ContinuousServingEngine:
             with mesh:
                 self.draft_pool = jax.device_put(
                     api.init_cache(self.draft_cfg, S, L), d_sh)
+            draft_cfg = self.draft_cfg
+
+            def engine_spec_macro(params, draft_pool, pool, *vectors):
+                return speculative.spec_macro(
+                    params, draft_pool, pool, *vectors, draft_cfg=draft_cfg,
+                    cfg=cfg, num_rounds=serving.macro_ticks,
+                    gamma=serving.spec_gamma,
+                    temperature=serving.temperature, seed=serving.seed,
+                    fault_guard=serving.fault_guard)
+
             self._spec_fn = jax.jit(
-                shd.in_slot_pool(
-                    functools.partial(speculative.spec_macro,
-                                      draft_cfg=self.draft_cfg, cfg=cfg,
-                                      num_rounds=serving.macro_ticks,
-                                      gamma=serving.spec_gamma,
-                                      temperature=serving.temperature,
-                                      seed=serving.seed,
-                                      fault_guard=serving.fault_guard),
-                    mesh, slot_axes),
+                shd.in_slot_pool(engine_spec_macro, mesh, slot_axes),
                 in_shardings=(p_sh, d_sh, c_sh) + (v_sh,) * 6,
                 out_shardings=(d_sh, c_sh, buf2_sh, buf2_sh, buf2_sh,
                                buf_sh),
                 donate_argnums=(1, 2))
-        self._sample_fn = jax.jit(
-            functools.partial(sampling.sample_tokens,
-                              temperature=serving.temperature,
-                              seed=serving.seed))
+        def engine_sample_first(logits, rids, idxs):
+            return sampling.sample_tokens(logits, rids, idxs,
+                                          temperature=serving.temperature,
+                                          seed=serving.seed)
+
+        self._sample_fn = jax.jit(engine_sample_first)
         # Slot ops: slot index is a traced scalar -> one compile each, and
         # out-shardings pinned to the pool's (slot-stable, never reshards).
         # The batch=1 source cache is pinned replicated, so a write_slot is
@@ -962,67 +982,103 @@ class ContinuousServingEngine:
             # via the *old* device mapping first, so a freed page always
             # hands zeros to its next owner).
             pg_sh = c_sh.pages
+
+            def engine_write_slot(pool, src, i, st):
+                return api.write_slot(cfg, pool, src, i, st)
+
+            def engine_reset_slot(pool, i, st):
+                return api.reset_slot(cfg, pool, i, st)
+
             self._write_fn = jax.jit(
-                lambda pool, src, i, st: api.write_slot(cfg, pool, src, i,
-                                                        st),
+                engine_write_slot,
                 in_shardings=(c_sh, rep_sh, None, pg_sh),
                 out_shardings=c_sh, donate_argnums=(0,))
             self._reset_fn = jax.jit(
-                lambda pool, i, st: api.reset_slot(cfg, pool, i, st),
+                engine_reset_slot,
                 in_shardings=(c_sh, None, pg_sh), out_shardings=c_sh,
                 donate_argnums=(0,))
         else:
+            def engine_write_slot(pool, src, i):
+                return api.write_slot(cfg, pool, src, i)
+
+            def engine_reset_slot(pool, i):
+                return api.reset_slot(cfg, pool, i)
+
             self._write_fn = jax.jit(
-                lambda pool, src, i: api.write_slot(cfg, pool, src, i),
+                engine_write_slot,
                 in_shardings=(c_sh, rep_sh, None), out_shardings=c_sh,
                 donate_argnums=(0,))
             self._reset_fn = jax.jit(
-                lambda pool, i: api.reset_slot(cfg, pool, i),
+                engine_reset_slot,
                 in_shardings=(c_sh, None), out_shardings=c_sh,
                 donate_argnums=(0,))
+
         # Fault injection (chaos harness only): NaN one slot's float
         # state. Same slot-stable donated-update shape as reset_slot;
         # never compiled unless an injector actually fires.
+        def engine_corrupt_slot(pool, i):
+            return api.corrupt_slot(cfg, pool, i)
+
         self._corrupt_fn = jax.jit(
-            lambda pool, i: api.corrupt_slot(cfg, pool, i),
-            in_shardings=(c_sh, None), out_shardings=c_sh,
-            donate_argnums=(0,))
-        self._chunk_fn = jax.jit(
-            lambda p, c, t: api.prefill_chunk(cfg, p, c, t),
-            donate_argnums=(1,))
+            engine_corrupt_slot, in_shardings=(c_sh, None),
+            out_shardings=c_sh, donate_argnums=(0,))
+
+        def engine_prefill_chunk(p, c, t):
+            return api.prefill_chunk(cfg, p, c, t)
+
         # Pre-embedded prefill chunks (vision patch prefix): same donated
         # continuation, fed (1, Lc, d) rows instead of token ids — this is
         # what lets an oversized vision prompt absorb its patch prefix
         # chunk-by-chunk instead of being rejected at admission (§11).
-        self._chunk_embeds_fn = jax.jit(
-            lambda p, c, e: api.prefill_chunk(cfg, p, c, None, embeds=e),
-            donate_argnums=(1,))
-        self._prefill_fn = jax.jit(
-            lambda p, b: api.prefill(p, cfg, b, max_len=L))
-        self._prefill_masked_fn = jax.jit(
-            lambda p, b, n: api.prefill(p, cfg, b, max_len=L, true_len=n))
+        def engine_prefill_chunk_embeds(p, c, e):
+            return api.prefill_chunk(cfg, p, c, None, embeds=e)
+
+        def engine_prefill(p, b):
+            return api.prefill(p, cfg, b, max_len=L)
+
+        def engine_prefill_masked(p, b, n):
+            return api.prefill(p, cfg, b, max_len=L, true_len=n)
+
+        self._chunk_fn = jax.jit(engine_prefill_chunk, donate_argnums=(1,))
+        self._chunk_embeds_fn = jax.jit(engine_prefill_chunk_embeds,
+                                        donate_argnums=(1,))
+        self._prefill_fn = jax.jit(engine_prefill)
+        self._prefill_masked_fn = jax.jit(engine_prefill_masked)
         if self._spec:
             # Draft-pool twins of the slot/prefill ops. The draft pool is
             # never paged (constant-state — nothing to page), so these are
             # always the unpaged shapes.
             dcfg = self.draft_cfg
             d_sh = self._draft_sharding
+
+            def engine_draft_write_slot(pool, src, i):
+                return api.write_slot(dcfg, pool, src, i)
+
+            def engine_draft_reset_slot(pool, i):
+                return api.reset_slot(dcfg, pool, i)
+
             self._dwrite_fn = jax.jit(
-                lambda pool, src, i: api.write_slot(dcfg, pool, src, i),
+                engine_draft_write_slot,
                 in_shardings=(d_sh, rep_sh, None), out_shardings=d_sh,
                 donate_argnums=(0,))
             self._dreset_fn = jax.jit(
-                lambda pool, i: api.reset_slot(dcfg, pool, i),
+                engine_draft_reset_slot,
                 in_shardings=(d_sh, None), out_shardings=d_sh,
                 donate_argnums=(0,))
-            self._dchunk_fn = jax.jit(
-                lambda p, c, t: api.prefill_chunk(dcfg, p, c, t),
-                donate_argnums=(1,))
-            self._dprefill_fn = jax.jit(
-                lambda p, b: api.prefill(p, dcfg, b, max_len=L))
-            self._dprefill_masked_fn = jax.jit(
-                lambda p, b, n: api.prefill(p, dcfg, b, max_len=L,
-                                            true_len=n))
+
+            def engine_draft_prefill_chunk(p, c, t):
+                return api.prefill_chunk(dcfg, p, c, t)
+
+            def engine_draft_prefill(p, b):
+                return api.prefill(p, dcfg, b, max_len=L)
+
+            def engine_draft_prefill_masked(p, b, n):
+                return api.prefill(p, dcfg, b, max_len=L, true_len=n)
+
+            self._dchunk_fn = jax.jit(engine_draft_prefill_chunk,
+                                      donate_argnums=(1,))
+            self._dprefill_fn = jax.jit(engine_draft_prefill)
+            self._dprefill_masked_fn = jax.jit(engine_draft_prefill_masked)
         if journal is not None and journal.nbytes == 0:
             # Fresh journal: stamp the sampling/geometry contract once.
             # restore() refuses a journal whose stream keying or sampling
@@ -1138,37 +1194,41 @@ class ContinuousServingEngine:
         tick, so deadlines are enforced at per-tick granularity even
         under K-tick macro-stepping."""
         sched = self.sched
-        sched.poll_arrivals(self.tick)
-        did = False
-        with self.mesh:
-            self._lifecycle_sweep()
-            if self._injector is not None:
-                self._apply_injections()
-            if sched.want_prefill(self._prefill is not None):
-                self.metrics.sample(sched.queue_depth, sched.occupancy)
-                self._prefill_tick()
-                sched.note_prefill()
-                self.metrics.prefill_ticks += 1
-                self.tick += 1
-                did = True
-            elif sched.active:
-                if self._spec:
-                    self._decode_spec()
+        with TraceAnnotation("engine.step") as span:
+            sched.poll_arrivals(self.tick)
+            kind = "idle"
+            with self.mesh:
+                self._lifecycle_sweep()
+                if self._injector is not None:
+                    self._apply_injections()
+                if sched.want_prefill(self._prefill is not None):
+                    kind = "prefill"
+                    self.metrics.sample(sched.queue_depth, sched.occupancy)
+                    self._prefill_tick()
+                    sched.note_prefill()
+                    self.metrics.prefill_ticks += 1
+                    self.tick += 1
+                elif sched.active:
+                    kind = "decode"
+                    if sched.ready and sched.free:
+                        self.metrics.decode_dispatches_while_ready += 1
+                    if self._spec:
+                        self._decode_spec()
+                    else:
+                        self._decode_macro()
                 else:
-                    self._decode_macro()
-                did = True
-            else:
-                self.metrics.sample(sched.queue_depth, sched.occupancy)
-                self.tick += 1
-        self.metrics.ticks = self.tick
-        if self.journal is not None:
-            # One fsync per engine step = macro-step granularity: the
-            # K-tick decode dispatch batch-journals its emissions here.
-            self.journal.flush()
-            every = self.serving.checkpoint_every_ticks
-            if every and self.tick - self._last_ckpt_tick >= every:
-                self.checkpoint()
-        return did or bool(sched.waiting)
+                    self.metrics.sample(sched.queue_depth, sched.occupancy)
+                    self.tick += 1
+            span.set_metadata(kind=kind)
+            self.metrics.ticks = self.tick
+            if self.journal is not None:
+                # One fsync per engine step = macro-step granularity: the
+                # K-tick decode dispatch batch-journals its emissions here.
+                self.journal.flush()
+                every = self.serving.checkpoint_every_ticks
+                if every and self.tick - self._last_ckpt_tick >= every:
+                    self.checkpoint()
+        return kind != "idle" or bool(sched.waiting)
 
     def run(self, requests: list[Request] | None = None, *,
             max_ticks: int | None = None):
@@ -1219,10 +1279,11 @@ class ContinuousServingEngine:
             raise RuntimeError(
                 "checkpointing requires the engine to have a journal "
                 "(ContinuousServingEngine(..., journal=Journal(path)))")
-        self.journal.flush()
-        state = checkpoint_lib.snapshot_engine(self)
-        path = checkpoint_lib.checkpoint_path(self._ckpt_dir, self.tick)
-        checkpoint_lib.save(path, state)
+        with TraceAnnotation("engine.checkpoint"):
+            self.journal.flush()
+            state = checkpoint_lib.snapshot_engine(self)
+            path = checkpoint_lib.checkpoint_path(self._ckpt_dir, self.tick)
+            checkpoint_lib.save(path, state)
         self._last_ckpt_tick = self.tick
         self.metrics.checkpoints_written += 1
         return path
@@ -1531,43 +1592,46 @@ class ContinuousServingEngine:
         pf.prefix_offset = (self.cfg.num_patches
                             if self.cfg.frontend == "vision" else 0)
 
-    def _prefill_tick(self):
-        pf = self._prefill
-        C = self.serving.prefill_chunk
-        if pf is None:
-            slot_ok = None
-            if self.page_pool is not None:
-                slot_ok = (lambda s, r:
-                           self.page_pool.can_alloc(s, self._need_rows(r)))
-            admission = self.sched.next_admission(slot_ok)
-            if admission is None:
-                return
-            rid, req, slot = admission
-            pf = _Prefill(rid, req, slot,
-                          api.init_cache(self.cfg, 1, self.serving.max_len))
-            if self._spec:
-                # Dual-cache residency (§13): the draft twin absorbs the
-                # same prompt so both regimes enter decode in agreement.
-                pf.draft = api.init_cache(self.draft_cfg, 1,
-                                          self.serving.max_len)
-            if self.page_pool is not None:
-                # Host-side reservation only: the device PageState learns
-                # the mapping at install (write_slot) time, so an
-                # admission cancelled mid-prefill frees host-side with no
-                # device op — and freshly freed pages are zeros (reset
-                # zeroes them via the old mapping), never stale bytes.
-                self.page_pool.alloc(slot, self._need_rows(req))
-                self._note_pages()
-            if self.prefix_cache is not None and self._chunkable and C:
-                self._seed_from_prefix(pf, C)
-            self._prefill = pf
-            self.metrics.per_request[rid].admitted = self.tick
-            self.metrics.per_request[rid].slot = slot
-        req, prompt = pf.req, np.asarray(pf.req.prompt, np.int32)
-        logits = pf.logits
-        if logits is not None:
-            pass                     # full prefix-cache hit: nothing to run
-        elif self._chunkable and C:
+    def _admit(self, C: int) -> _Prefill | None:
+        """Pop the next request into a reserved slot and start its prefill
+        (cache, pages, prefix seeding), or None when none can be admitted."""
+        slot_ok = None
+        if self.page_pool is not None:
+            slot_ok = (lambda s, r:
+                       self.page_pool.can_alloc(s, self._need_rows(r)))
+        admission = self.sched.next_admission(slot_ok)
+        if admission is None:
+            return None
+        rid, req, slot = admission
+        pf = _Prefill(rid, req, slot,
+                      api.init_cache(self.cfg, 1, self.serving.max_len))
+        if self._spec:
+            # Dual-cache residency (§13): the draft twin absorbs the
+            # same prompt so both regimes enter decode in agreement.
+            pf.draft = api.init_cache(self.draft_cfg, 1,
+                                      self.serving.max_len)
+        if self.page_pool is not None:
+            # Host-side reservation only: the device PageState learns
+            # the mapping at install (write_slot) time, so an
+            # admission cancelled mid-prefill frees host-side with no
+            # device op — and freshly freed pages are zeros (reset
+            # zeroes them via the old mapping), never stale bytes.
+            self.page_pool.alloc(slot, self._need_rows(req))
+            self._note_pages()
+        if self.prefix_cache is not None and self._chunkable and C:
+            self._seed_from_prefix(pf, C)
+        self._prefill = pf
+        st = self.metrics.per_request[rid]
+        st.admitted = self.tick
+        st.admitted_wall = self._clock()
+        st.slot = slot
+        return pf
+
+    def _prefill_chunk(self, pf: _Prefill, prompt: np.ndarray, C: int):
+        """Dispatch the next piece of a prompt's prefill: one chunk, or the
+        whole prompt on the non-chunkable paths. Returns its logits (None
+        after a vision-prefix chunk, which yields none)."""
+        if self._chunkable and C:
             patches = (self.cfg.num_patches
                        if self.cfg.frontend == "vision" else 0)
             if pf.prefix_offset < patches:
@@ -1581,7 +1645,7 @@ class ContinuousServingEngine:
                 _, pf.cache = self._chunk_embeds_fn(self.params, pf.cache,
                                                     emb)
                 pf.prefix_offset += n
-                return
+                return None
             chunk = prompt[pf.offset:pf.offset + C]
             toks = jnp.asarray(chunk[None, :])
             logits, pf.cache = self._chunk_fn(self.params, pf.cache, toks)
@@ -1627,15 +1691,42 @@ class ContinuousServingEngine:
             if self._spec:
                 _, pf.draft = self._dprefill_fn(self.params, batch)
             pf.offset = len(prompt)
+        return logits
+
+    def _prefill_tick(self):
+        pf = self._prefill
+        C = self.serving.prefill_chunk
+        if pf is None:
+            with TraceAnnotation("engine.admit") as span:
+                pf = self._admit(C)
+                if pf is None:
+                    return
+                span.set_metadata(rid=pf.rid, slot=pf.slot)
+        prompt = np.asarray(pf.req.prompt, np.int32)
+        logits = pf.logits
+        if logits is None:           # not None: a full prefix-cache hit
+            with TraceAnnotation("engine.prefill.chunk", rid=pf.rid,
+                                 offset=pf.offset):
+                logits = self._prefill_chunk(pf, prompt, C)
         if pf.offset < len(prompt):
             return                       # more chunks; decode may interleave
         # Prompt fully absorbed: sample the first token on device (same
         # fused sampler as the decode loop, idx 0) and install the request
         # into its pool slot. One int32 scalar crosses to host.
-        tok0 = int(self._sample_fn(
-            logits[:, -1, :], jnp.full((1,), pf.rid, jnp.int32),
-            jnp.zeros((1,), jnp.int32))[0])
+        with TraceAnnotation("engine.prefill.first_token", rid=pf.rid):
+            tok0 = int(self._sample_fn(
+                logits[:, -1, :], jnp.full((1,), pf.rid, jnp.int32),
+                jnp.zeros((1,), jnp.int32))[0])
         self.metrics.prefill_token_syncs += 1
+        with TraceAnnotation("engine.prefill.install", rid=pf.rid,
+                             slot=pf.slot):
+            self._install(pf, prompt, logits, tok0)
+
+    def _install(self, pf: _Prefill, prompt: np.ndarray, logits, tok0: int):
+        """Move a fully prefilled request into its pool slot: the slot
+        write, the host mirrors, and its first token's emission."""
+        C = self.serving.prefill_chunk
+        req = pf.req
         if (self.prefix_cache is not None and self._chunkable and C
                 and pf.logits is None):
             # Full-prompt entry with last-token logits: a repeat of this
@@ -1672,15 +1763,22 @@ class ContinuousServingEngine:
         """One decode dispatch = K device ticks for the whole pool; replay
         the token buffer on host at per-tick granularity so streaming
         callbacks, TTFT/queue-depth samples, and eviction stay exact."""
-        self.pool, toks, em, flt = self._macro_fn(
-            self.params, self.pool, jnp.asarray(self._last_tok),
-            jnp.asarray(self._active), jnp.asarray(self._rids),
-            jnp.asarray(self._gen), jnp.asarray(self._eos),
-            jnp.asarray(self._maxn))
+        with TraceAnnotation("engine.decode.launch"):
+            self.pool, toks, em, flt = self._macro_fn(
+                self.params, self.pool, jnp.asarray(self._last_tok),
+                jnp.asarray(self._active), jnp.asarray(self._rids),
+                jnp.asarray(self._gen), jnp.asarray(self._eos),
+                jnp.asarray(self._maxn))
         self.metrics.decode_dispatches += 1
-        toks, em, flt = (np.asarray(toks), np.asarray(em),
-                         np.asarray(flt))  # ONE host sync per K ticks
+        with TraceAnnotation("engine.decode.wait"):
+            toks, em, flt = (np.asarray(toks), np.asarray(em),
+                             np.asarray(flt))  # ONE host sync per K ticks
         self.metrics.host_syncs += 1
+        with TraceAnnotation("engine.decode.replay"):
+            self._replay_macro(toks, em, flt)
+
+    def _replay_macro(self, toks, em, flt):
+        """Replay a macro-step's (K, S) buffers tick by tick."""
         for t in range(toks.shape[0]):
             if not (em[t].any() or flt[t].any()):
                 break   # every slot drained mid-macro-step; suffix unused
@@ -1724,16 +1822,23 @@ class ContinuousServingEngine:
         lifecycle sweep after — run exactly like the plain macro-step's
         replay; only the tokens-per-tick arithmetic changes. Still one
         host sync per dispatch."""
-        G = self.serving.spec_gamma
-        self.draft_pool, self.pool, toks, em, flt, acc = self._spec_fn(
-            self.params, self.draft_pool, self.pool,
-            jnp.asarray(self._last_tok), jnp.asarray(self._active),
-            jnp.asarray(self._rids), jnp.asarray(self._gen),
-            jnp.asarray(self._eos), jnp.asarray(self._maxn))
+        with TraceAnnotation("engine.decode.launch"):
+            self.draft_pool, self.pool, toks, em, flt, acc = self._spec_fn(
+                self.params, self.draft_pool, self.pool,
+                jnp.asarray(self._last_tok), jnp.asarray(self._active),
+                jnp.asarray(self._rids), jnp.asarray(self._gen),
+                jnp.asarray(self._eos), jnp.asarray(self._maxn))
         self.metrics.decode_dispatches += 1
-        toks, em, flt, acc = (np.asarray(toks), np.asarray(em),
-                              np.asarray(flt), np.asarray(acc))
+        with TraceAnnotation("engine.decode.wait"):
+            toks, em, flt, acc = (np.asarray(toks), np.asarray(em),
+                                  np.asarray(flt), np.asarray(acc))
         self.metrics.host_syncs += 1      # ONE host sync per K rounds
+        with TraceAnnotation("engine.decode.replay"):
+            self._replay_spec(toks, em, flt, acc)
+
+    def _replay_spec(self, toks, em, flt, acc):
+        """Replay a speculative dispatch's buffers one round per tick."""
+        G = self.serving.spec_gamma
         for r in range(toks.shape[0]):
             if not (em[r].any() or flt[r].any()):
                 break   # every slot drained mid-dispatch; suffix unused

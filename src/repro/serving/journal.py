@@ -47,6 +47,8 @@ import json
 import os
 import zlib
 
+from jax.profiler import TraceAnnotation
+
 JOURNAL_VERSION = 1
 JOURNAL_NAME = "journal.wal"
 
@@ -154,9 +156,10 @@ class Journal:
             return
         blob = b"".join(self._buf)
         self._buf.clear()
-        self._f.write(blob)
-        self._f.flush()
-        os.fsync(self._f.fileno())
+        with TraceAnnotation("engine.journal.flush"):
+            self._f.write(blob)
+            self._f.flush()
+            os.fsync(self._f.fileno())
         self.nbytes += len(blob)
         self.flushes += 1
 
