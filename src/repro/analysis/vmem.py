@@ -75,7 +75,7 @@ class KernelFootprint:
 def _nbytes(shape, dtype) -> int:
     n = 1
     for dim in shape:
-        n *= int(dim)
+        n *= dim if isinstance(dim, int) else 1     # squeezed: one row
     return n * int(np.dtype(dtype).itemsize)
 
 
@@ -105,8 +105,17 @@ def record_pallas_calls(records: list, module_label: str):
     real = pl.pallas_call
 
     def recorder(kernel, *, grid=None, in_specs=None, out_specs=None,
-                 out_shape=None, scratch_shapes=None, **_kwargs):
+                 out_shape=None, scratch_shapes=None, grid_spec=None,
+                 **_kwargs):
+        prefetch = 0
+        if grid_spec is not None:           # scalar-prefetch grid spec
+            grid, in_specs = grid_spec.grid, grid_spec.in_specs
+            out_specs = grid_spec.out_specs
+            scratch_shapes = grid_spec.scratch_shapes
+            prefetch = grid_spec.num_scalar_prefetch
+
         def run(*args):
+            args = args[prefetch:]          # prefetched scalars sit in SMEM
             outs = _aslist(out_shape)
             in_bytes = 0
             for spec, arg in zip(_aslist(in_specs), args):
@@ -186,17 +195,16 @@ def _probe_all() -> list[KernelFootprint]:
     run("slay_fused", functools.partial(slay_fused._bwd_impl, fst),
         q, k, v, anchors, omegas, y, den, y)
 
-    # decode_step: one-token serving step (plain + active-masked).
+    # decode_step: one-token serving step on the layer-stacked pool state
+    # (the plain step is the same kernel at one layer, one row per slot).
     dst = decode_step.DecodeStatics(delta=1e-6, interpret=True)
     dqf = sds((_DEC_BK * _DEC_G, _M), f32)
     dkf, dvv = sds((_DEC_BK, _M), f32), sds((_DEC_BK, _DV), f32)
-    s = sds((_DEC_BK, _M, _DV), f32)
-    z = sds((_DEC_BK, _M), f32)
-    active = sds((_DEC_BK,), jnp.int32)
-    run("decode_step", functools.partial(decode_step._decode_impl, dst),
-        dqf, dkf, dvv, s, z)
-    run("decode_step", functools.partial(decode_step._decode_masked, dst),
-        dqf, dkf, dvv, s, z, active)
+    run("decode_step", functools.partial(decode_step._decode_stacked, dst,
+                                         kv_heads=_DEC_BK // 2),
+        dqf, dkf, dvv, sds((2, _DEC_BK, _M, _DV), f32),
+        sds((2, _DEC_BK, _M), f32), sds((1,), jnp.int32),
+        sds((_DEC_BK,), jnp.int32))
 
     return records
 

@@ -131,7 +131,8 @@ def slay_fused_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 def decode_linear_step(qf: jnp.ndarray, kf: jnp.ndarray, v: jnp.ndarray,
                        s: jnp.ndarray, z: jnp.ndarray,
-                       active: jnp.ndarray | None = None, *,
+                       active: jnp.ndarray | None = None,
+                       layer: jnp.ndarray | None = None, *,
                        delta: float = 1e-6,
                        interpret: bool | None = None):
     """One-token linear-attention decode step from the *model* layout.
@@ -146,6 +147,12 @@ def decode_linear_step(qf: jnp.ndarray, kf: jnp.ndarray, v: jnp.ndarray,
     through bit-identical), so an idle slot costs only block pipelining.
     Runs the jnp oracle off-TPU, with identical masked semantics.
 
+    ``layer`` (int32 scalar) takes the decode cache's layer-stacked state,
+    s (L, B, Hkv, m, dv) and z (L, B, Hkv, m), and updates layer
+    ``layer`` in place (the kernel's index maps select it; the oracle
+    writes it back with a dynamic update on the stack); s' and z' are the
+    whole stacks.
+
     Inside a slot-sharded serving pool (``sharding.current_slot_pool``)
     the kernel runs once per shard under ``shard_map`` over the slot dim:
     GSPMD cannot partition a ``pallas_call`` and would otherwise gather
@@ -154,39 +161,56 @@ def decode_linear_step(qf: jnp.ndarray, kf: jnp.ndarray, v: jnp.ndarray,
     pool = shd.current_slot_pool()
     if _use_kernel(interpret) and pool is not None and pool[1]:
         mesh, axes = pool
-        spec = P(axes[0] if len(axes) == 1 else axes)
-        args = (qf, kf, v, s, z) + (() if active is None else (active,))
+        row = P(axes[0] if len(axes) == 1 else axes)
+        state = row if layer is None else P(None, *row)
+        opt = {k: x for k, x in (("active", active), ("layer", layer))
+               if x is not None}
+        opt_specs = {"active": row, "layer": P()}
 
-        def local(*a):
-            return _decode_linear_step(*a, delta=delta, interpret=interpret)
-        return jax.shard_map(local, mesh=mesh, in_specs=(spec,) * len(args),
-                             out_specs=(spec,) * 3, check_vma=False)(*args)
-    return _decode_linear_step(qf, kf, v, s, z, active, delta=delta,
+        def local(qf, kf, v, s, z, opt):
+            return _decode_linear_step(qf, kf, v, s, z, **opt, delta=delta,
+                                       interpret=interpret)
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(row, row, row, state, state,
+                      {k: opt_specs[k] for k in opt}),
+            out_specs=(row, state, state),
+            check_vma=False)(qf, kf, v, s, z, opt)
+    return _decode_linear_step(qf, kf, v, s, z, active, layer, delta=delta,
                                interpret=interpret)
 
 
-def _decode_linear_step(qf, kf, v, s, z, active=None, *, delta, interpret):
+def _decode_linear_step(qf, kf, v, s, z, active=None, layer=None, *,
+                        delta: float, interpret: bool | None):
     B, H, m = qf.shape
     hkv, dv = kf.shape[-2], v.shape[-1]
     g = H // hkv
+    lead = s.shape[:-4]                      # (L,) when stacked, else ()
     qh = qf.reshape(B * hkv * g, m)          # model heads are kv-major
     kh = kf.reshape(B * hkv, m)
     vh = v.reshape(B * hkv, dv)
-    sh = s.reshape(B * hkv, m, dv)
-    zh = z.reshape(B * hkv, m)
+    sh = s.reshape(*lead, B * hkv, m, dv)
+    zh = z.reshape(*lead, B * hkv, m)
     ah = None
     if active is not None:
         ah = jnp.broadcast_to(active.astype(jnp.int32)[:, None],
                               (B, hkv)).reshape(B * hkv)
-    if not _use_kernel(interpret):
+    if _use_kernel(interpret):
+        y, s2, z2 = _dk.decode_linear_attention(qh, kh, vh, sh, zh, ah, layer,
+                                                delta=delta,
+                                                interpret=bool(interpret),
+                                                kv_heads=hkv)
+    elif layer is None:
         y, s2, z2 = _ref.decode_linear_attention_ref(qh, kh, vh, sh, zh, ah,
                                                      delta=delta)
     else:
-        y, s2, z2 = _dk.decode_linear_attention(qh, kh, vh, sh, zh, ah,
-                                                delta=delta,
-                                                interpret=bool(interpret))
-    return (y.reshape(B, H, dv), s2.reshape(B, hkv, m, dv),
-            z2.reshape(B, hkv, m))
+        y, sl, zl = _ref.decode_linear_attention_ref(
+            qh, kh, vh, jax.lax.dynamic_index_in_dim(sh, layer, 0, False),
+            jax.lax.dynamic_index_in_dim(zh, layer, 0, False), ah,
+            delta=delta)
+        s2 = jax.lax.dynamic_update_index_in_dim(sh, sl, layer, 0)
+        z2 = jax.lax.dynamic_update_index_in_dim(zh, zl, layer, 0)
+    return y.reshape(B, H, dv), s2.reshape(s.shape), z2.reshape(z.shape)
 
 
 def slay_features(u: jnp.ndarray, params: dict, cfg: SlayFeatureConfig, *,

@@ -256,7 +256,7 @@ def _yat_scores(kind: str, qg, kb):
 
 def decode_step(spec: AttentionSpec, params: dict | None, q, k, v,
                 cache: AttnCache, *,
-                active=None) -> tuple[jnp.ndarray, AttnCache]:
+                active=None, layer=None) -> tuple[jnp.ndarray, AttnCache]:
     """One token. q (..., H, Dh), k/v (..., Hkv, *) -> (..., H, dv).
 
     ``active`` (B,) bool/int masks continuous-batching pool rows: drained
@@ -265,6 +265,11 @@ def decode_step(spec: AttentionSpec, params: dict | None, q, k, v,
     contract as the Pallas decode kernel's active-row mask, so the
     reference path and the kernel path are interchangeable mid-stream.
     Requires per-slot (vector) ``pos`` when given.
+
+    ``layer`` (int32 scalar, linear kinds): ``cache.s``/``cache.z`` hold
+    the state of every layer, stacked on a leading axis, and only layer
+    ``layer`` is read and updated, in place; the returned cache holds the
+    whole stacks. KV kinds ignore it (their leaves are one layer's).
     """
     act = None
     if active is not None:
@@ -280,14 +285,21 @@ def decode_step(spec: AttentionSpec, params: dict | None, q, k, v,
             # (jnp oracle off-TPU — identical masked semantics).
             from repro.kernels import ops
             y, s2, z2 = ops.decode_linear_step(qf, kf, v, cache.s, cache.z,
-                                               active)
+                                               active, layer)
             return y, AttnCache(None, None, cache.pos + step, s2, z2)
-        y, st = la.decode_step(qf, kf, v, la.LinearState(cache.s, cache.z))
-        if act is None:
-            return y, AttnCache(None, None, cache.pos + 1, st.s, st.z)
-        s2 = jnp.where(act[:, None, None, None], st.s, cache.s)
-        z2 = jnp.where(act[:, None, None], st.z, cache.z)
-        y = jnp.where(act[:, None, None], y, 0).astype(y.dtype)
+        s, z = cache.s, cache.z
+        if layer is not None:
+            s = jax.lax.dynamic_index_in_dim(s, layer, 0, keepdims=False)
+            z = jax.lax.dynamic_index_in_dim(z, layer, 0, keepdims=False)
+        y, st = la.decode_step(qf, kf, v, la.LinearState(s, z))
+        s2, z2 = st.s, st.z
+        if act is not None:
+            s2 = jnp.where(act[:, None, None, None], s2, s)
+            z2 = jnp.where(act[:, None, None], z2, z)
+            y = jnp.where(act[:, None, None], y, 0).astype(y.dtype)
+        if layer is not None:
+            s2 = jax.lax.dynamic_update_index_in_dim(cache.s, s2, layer, 0)
+            z2 = jax.lax.dynamic_update_index_in_dim(cache.z, z2, layer, 0)
         return y, AttnCache(None, None, cache.pos + step, s2, z2)
 
     size = cache.k.shape[-3]

@@ -396,16 +396,25 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
     SSM output — the jitted pool dispatch stays one fixed-shape call while
     idle slots stop advancing. Their logits rows are meaningless and must
     be masked by the caller (the engine samples only active rows).
+
+    Linear attention's (S, z) state rides the layer scan's carry as the
+    whole layer stack, not its scanned ``xs``/``ys``: each layer updates
+    its own slice in place (``attention.decode_step(layer=...)``), so the
+    pool's state is never sliced out, written back or copied per tick.
+    KV rings and SSM carries, one layer's at a time, stay scanned.
     """
     x = embed(params["embed"], tokens[:, 0]).astype(cfg.activation_dtype)
     pos = cache.pos
     act = None if active is None else active.astype(bool)
     slay_params = params.get("slay")
     kinds = jnp.asarray(_layer_kinds(cfg))
+    stacked = cache.attn is not None and cache.attn.s is not None
 
-    def body(x, scanned):
+    def body(carry, scanned):
+        x, state = carry
         lp = scanned["params"]
         is_local = scanned["kind"]
+        layer = scanned.get("layer")
         new = {}
         if cfg.family == "ssm":
             y, st = ssm.ssd_decode_step(
@@ -416,7 +425,7 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
             new["ssm"] = _state_passthrough(st, scanned["ssm"], act)
             if act is not None:
                 y = jnp.where(act[:, None], y, 0).astype(y.dtype)
-            return x + y, new
+            return (x + y, state), new
         xa = rmsnorm(lp["pre_attn"], x)
         q = jnp.einsum("bd,dhk->bhk", xa, lp["attn"]["wq"])
         k = jnp.einsum("bd,dhk->bhk", xa, lp["attn"]["wk"])
@@ -429,6 +438,8 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
         k = rope(k[:, None], p1, cfg.rope_theta)[:, 0]
         spec_g = cfg.attention_spec(local=False)
         ac = scanned["attn"]
+        if stacked:
+            ac = ac._replace(s=state[0], z=state[1])
         if cfg.local_global_period and cfg.local_window:
             spec_l = cfg.attention_spec(local=True)
 
@@ -439,7 +450,7 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
 
             def _global():
                 y, c = attn.decode_step(spec_g, slay_params, q, k, v, ac,
-                                        active=act)
+                                        active=act, layer=layer)
                 return y, _merge_cache(ac, c)
 
             y, nac = jax.lax.cond(is_local == 1, _local, _global)
@@ -458,8 +469,11 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
                 v=pg.scatter_ring(ac.v, nd.v, cache.pages))
         else:
             y, nac = attn.decode_step(spec_g, slay_params, q, k, v, ac,
-                                      active=act)
+                                      active=act, layer=layer)
         a = jnp.einsum("bhk,hkd->bd", y, lp["attn"]["wo"])
+        if stacked:
+            state = (nac.s, nac.z)
+            nac = nac._replace(s=None, z=None)
         new["attn"] = nac
         if cfg.family == "hybrid":
             m, st = ssm.ssd_decode_step(
@@ -478,14 +492,21 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
             y2 = y2[:, 0]
         else:
             y2 = mlp(lp["mlp"], xm, cfg.gated_mlp)
-        return x + y2, new
+        return (x + y2, state), new
 
     scanned = {"params": params["layers"], "kind": kinds}
+    state = None
     if cache.attn is not None:
         scanned["attn"] = cache.attn
+    if stacked:
+        state = (cache.attn.s, cache.attn.z)
+        scanned["attn"] = cache.attn._replace(s=None, z=None)
+        scanned["layer"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     if cache.ssm is not None:
         scanned["ssm"] = cache.ssm
-    x, new = jax.lax.scan(body, x, scanned)
+    (x, state), new = jax.lax.scan(body, (x, state), scanned)
+    if stacked:
+        new["attn"] = new["attn"]._replace(s=state[0], z=state[1])
     x = rmsnorm(params["final_norm"], x)
     table = params.get("unembed", params["embed"])
     logits = unembed(table, x, cfg.final_logit_softcap)

@@ -170,6 +170,104 @@ def test_masked_decode_requires_vector_pos():
                          active=jnp.asarray([True]))
 
 
+def _decode_step_xs_ys(params, cfg, cache, tokens, active):
+    """Oracle: the layer scan that slices each layer's linear (S, z) out of
+    the stack as scanned ``xs`` and writes it back as ``ys`` — the
+    formulation ``transformer.decode_step`` replaced with an in-place
+    carry. Covers the dense, local/global and hybrid layer bodies."""
+    from repro.models import layers, ssm
+    from repro.models import transformer as tf
+    x = layers.embed(params["embed"], tokens[:, 0]).astype(
+        cfg.activation_dtype)
+    pos = cache.pos
+    act = active.astype(bool)
+    slay_params = params.get("slay")
+
+    def body(x, scanned):
+        lp, ac, new = scanned["params"], scanned["attn"], {}
+        xa = layers.rmsnorm(lp["pre_attn"], x)
+        q, k, v = (jnp.einsum("bd,dhk->bhk", xa, lp["attn"][w])
+                   for w in ("wq", "wk", "wv"))
+        p1 = pos[:, None]
+        q = layers.rope(q[:, None], p1, cfg.rope_theta)[:, 0]
+        k = layers.rope(k[:, None], p1, cfg.rope_theta)[:, 0]
+        spec_g = cfg.attention_spec(local=False)
+        if cfg.local_global_period and cfg.local_window:
+            spec_l = cfg.attention_spec(local=True)
+
+            def _local():
+                y, c = attn.decode_step(spec_l, None, q, k, v, ac,
+                                        active=act)
+                return y, tf._merge_cache(ac, c)
+
+            def _global():
+                y, c = attn.decode_step(spec_g, slay_params, q, k, v, ac,
+                                        active=act)
+                return y, tf._merge_cache(ac, c)
+
+            y, new["attn"] = jax.lax.cond(scanned["kind"] == 1, _local,
+                                          _global)
+        else:
+            y, new["attn"] = attn.decode_step(spec_g, slay_params, q, k, v,
+                                              ac, active=act)
+        a = jnp.einsum("bhk,hkd->bd", y, lp["attn"]["wo"])
+        if cfg.family == "hybrid":
+            m, st = ssm.ssd_decode_step(
+                lp["ssd"], xa, scanned["ssm"], d_state=cfg.ssm_state,
+                expand=cfg.ssm_expand, head_dim=cfg.ssm_head_dim,
+                ngroups=cfg.ssm_ngroups, conv_width=cfg.ssm_conv_width)
+            m = jnp.where(act[:, None], m, 0).astype(m.dtype)
+            a = 0.5 * (a + m)
+            new["ssm"] = tf._state_passthrough(st, scanned["ssm"], act)
+        x = x + a
+        xm = layers.rmsnorm(lp["pre_mlp"], x)
+        return x + layers.mlp(lp["mlp"], xm, cfg.gated_mlp), new
+
+    scanned = {"params": params["layers"], "attn": cache.attn,
+               "kind": jnp.asarray(tf._layer_kinds(cfg))}
+    if cache.ssm is not None:
+        scanned["ssm"] = cache.ssm
+    x, new = jax.lax.scan(body, x, scanned)
+    x = layers.rmsnorm(params["final_norm"], x)
+    logits = layers.unembed(params.get("unembed", params["embed"]), x,
+                            cfg.final_logit_softcap)
+    return logits[:, None, :], tf.DecodeCache(
+        new["attn"], new.get("ssm"), pos + act.astype(jnp.int32),
+        cache.pages)
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("arch,use_pallas", [
+    ("slayformer-124m", True), ("slayformer-124m", False),
+    ("gemma2-27b", True), ("hymba-1.5b", True)])
+def test_decode_step_carry_matches_xs_ys_oracle(arch, use_pallas):
+    """The layer scan's in-place (S, z) carry is the old per-layer
+    slice/write-back formulation, bit for bit: logits and every cache leaf
+    over 3 masked ticks, for the dense, local/global (``lax.cond``) and
+    hybrid layer bodies, on the kernel's oracle and on the jnp path."""
+    cfg = configs.get_smoke_config(arch, use_pallas=use_pallas)
+    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    pool = api.init_cache(cfg, 3, 32)
+    for slot, n in ((0, 7), (2, 5)):
+        toks = jax.random.randint(jax.random.PRNGKey(slot + 1), (1, n), 0,
+                                  cfg.vocab_size)
+        _, req = api.prefill(params, cfg, {"tokens": toks}, max_len=32)
+        pool = api.write_slot(cfg, pool, req, slot)
+    assert pool.attn.s is not None        # the carry path is the one taken
+    new = jax.jit(lambda c, t, a: api.decode_step(params, cfg, c, t, a))
+    old = jax.jit(lambda c, t, a: _decode_step_xs_ys(params, cfg, c, t, a))
+    got = want = pool
+    for tick, live in enumerate(([1, 0, 1], [1, 1, 0], [0, 1, 1])):
+        tok = jnp.full((3, 1), 5 + tick, jnp.int32)
+        act = jnp.asarray(live, bool)
+        lg_got, got = new(got, tok, act)
+        lg_want, want = old(want, tok, act)
+        np.testing.assert_array_equal(np.asarray(lg_got), np.asarray(lg_want))
+        assert (jax.tree.structure(got) == jax.tree.structure(want))
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 # ---------------------------------------------------------------------------
 # K-tick macro-stepping
 # ---------------------------------------------------------------------------

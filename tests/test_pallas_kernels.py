@@ -178,6 +178,45 @@ def test_decode_kernel_active_mask(bh, bk, m, dv):
             assert np.all(np.asarray(y_k)[row * g:(row + 1) * g] == 0)
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_decode_kernel_stacked_state_in_place(layer):
+    """The serving form: the pool state stacked over 3 layers, the layer
+    picked by scalar prefetch. Layer ``layer``'s live rows match the oracle
+    on s[layer]; its drained rows and every other layer come back
+    bit-identical, and drained rows read out zero."""
+    from repro.kernels import decode_step as dk
+    nl, bh, bk, m, dv = 3, 8, 4, 24, 16
+    qf = jax.random.uniform(jax.random.PRNGKey(0), (bh, m))
+    kf = jax.random.uniform(jax.random.PRNGKey(1), (bk, m))
+    v = jax.random.normal(jax.random.PRNGKey(2), (bk, dv))
+    s = jax.random.uniform(jax.random.PRNGKey(3), (nl, bk, m, dv))
+    z = jax.random.uniform(jax.random.PRNGKey(4), (nl, bk, m)) + 1.0
+    active = jnp.asarray([1, 0, 0, 1], jnp.int32)
+    y_k, s_k, z_k = dk.decode_linear_attention(
+        qf, kf, v, s, z, active, jnp.int32(layer), interpret=True)
+    y_r, s_r, z_r = ref.decode_linear_attention_ref(qf, kf, v, s[layer],
+                                                    z[layer], active)
+    assert s_k.shape == s.shape and z_k.shape == z.shape
+    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r), atol=3e-5,
+                               rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(s_k[layer]), np.asarray(s_r),
+                               atol=3e-5)
+    np.testing.assert_allclose(np.asarray(z_k[layer]), np.asarray(z_r),
+                               atol=3e-5)
+    g = bh // bk
+    for row in (1, 2):                 # drained
+        np.testing.assert_array_equal(np.asarray(s_k[layer, row]),
+                                      np.asarray(s[layer, row]))
+        np.testing.assert_array_equal(np.asarray(z_k[layer, row]),
+                                      np.asarray(z[layer, row]))
+        assert np.all(np.asarray(y_k)[row * g:(row + 1) * g] == 0)
+    for other in set(range(nl)) - {layer}:
+        np.testing.assert_array_equal(np.asarray(s_k[other]),
+                                      np.asarray(s[other]))
+        np.testing.assert_array_equal(np.asarray(z_k[other]),
+                                      np.asarray(z[other]))
+
+
 def test_decode_kernel_sequence_consistency():
     """Repeated kernel decode steps == the chunked causal oracle rows."""
     from repro.kernels import decode_step as dk

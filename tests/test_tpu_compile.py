@@ -98,3 +98,50 @@ def test_kernel_compiles_for_v5e(one_chip, kernel):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_macro_decode_moves_pool_state_only_in_kernel(one_chip, monkeypatch):
+    """The serving macro-step (K = 2 ticks, 8 slots, 2 layers) updates the
+    layer-stacked SLAY state in place: no copy, dynamic slice or dynamic
+    update, fused or not, produces the stack or one layer of it, in the
+    model's layout or the kernel's view. The decode kernel is the only op
+    that touches it."""
+    from repro.analysis import hlo
+    from repro.kernels import ops
+    from repro.models import api
+    from repro.serving import engine
+
+    nl, slots, hkv = 2, 8, CFG.num_kv_heads
+    cfg = get_config("slayformer-124m", num_layers=nl)
+    # Whole-program compile for the described chip: take the TPU branch.
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(
+        lambda: api.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = placed(jax.eval_shape(lambda: api.init_cache(cfg, slots, 2048)))
+    vectors = [jax.ShapeDtypeStruct((slots,), dt, sharding=one_chip)
+               for dt in (jnp.int32, jnp.bool_, jnp.int32, jnp.int32,
+                          jnp.int32, jnp.int32)]
+
+    def macro(params, cache, *vectors):
+        return engine._macro_decode(params, cache, *vectors, cfg=cfg,
+                                    num_ticks=2, temperature=0.0, seed=0)
+
+    text = jax.jit(macro, donate_argnums=(1,)).lower(
+        params, cache, *vectors).compile().as_text()
+    assert "tpu_custom_call" in text
+    rows = slots * hkv
+    state = {f"f32[{','.join(map(str, s))}]" for s in (
+        (nl, slots, hkv, M, DV), (1, slots, hkv, M, DV), (slots, hkv, M, DV),
+        (nl, rows, M, DV), (1, rows, M, DV), (rows, M, DV),
+        (nl, rows, DV, M), (1, rows, DV, M), (rows, DV, M))}
+    moves = ("copy", "dynamic-slice", "dynamic-update-slice")
+    bad = [i.text[:160] for i in hlo.parse_hlo(text).instructions
+           if i.shape.split("{")[0] in state
+           and (hlo.base_opcode(i.opcode) in moves
+                or (i.opcode == "fusion" and any(m in i.name for m in moves)))]
+    assert not bad, "\n".join(bad)
