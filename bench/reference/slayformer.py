@@ -1,13 +1,17 @@
-"""Plain reference of slayformer-124m in both attention kinds.
+"""The slayformer family in both attention kinds, as the benchmark states
+it: the weights tree it serves (``shapes``, ``finish``) and its plain
+reference (``logits``). A configuration file names this module under
+``"model"``.
 
-Straight jax.numpy over one sequence at a time: no kernels, no cache, no
-batching tricks, no chunked scan. Causal SLAY attention is written in its
-quadratic form, y_i = sum_{j<=i} <Psi(q_i), Psi(k_j)> v_j / (sum_{j<=i}
-<Psi(q_i), Psi(k_j)> + delta), which equals the linear-time recurrence
-exactly in real arithmetic. It imports nothing of the program: the feature
-map, the quadrature nodes and the layer equations are written out here
-from the paper (Eqs. 2, 8-11) and from the model definition the
-configuration file states (RMSNorm, RoPE, SiLU MLP, tied embeddings).
+The reference is straight jax.numpy over one sequence at a time: no
+kernels, no cache, no batching tricks, no chunked scan. Causal SLAY
+attention is written in its quadratic form, y_i = sum_{j<=i} <Psi(q_i),
+Psi(k_j)> v_j / (sum_{j<=i} <Psi(q_i), Psi(k_j)> + delta), which equals
+the linear-time recurrence exactly in real arithmetic. It imports nothing
+of the program: the feature map, the quadrature nodes and the layer
+equations are written out here from the paper (Eqs. 2, 8-11) and from the
+model definition the configuration file states (RMSNorm, RoPE, SiLU MLP,
+tied embeddings).
 
 Precision: ``prec="float32"`` is the reference, every matmul at
 ``highest``. Any lower ``prec`` (the control) rounds every matmul operand
@@ -20,6 +24,49 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# The tied embedding is N(0, 0.02^2) as in GPT-2, small beside what the
+# layers add to the residual stream, so that the next token depends on the
+# context and not only on the current token (with a unit embedding, a
+# random tied model's top logit is the current token itself, and a broken
+# attention path would serve the same tokens).
+EMBED_FAN_IN = 2500          # std 1 / sqrt(2500) = 0.02
+
+
+def shapes(arch: dict) -> dict:
+    """Leaf shapes of the parameter tree, each with its init: the fan-in
+    of a matrix, 0 for a zero norm scale, -1 for an fp32 projection. The
+    tree has the layout the program's transformer reads (stacked
+    layers)."""
+    L, d, H = arch["num_layers"], arch["d_model"], arch["num_heads"]
+    Hkv, dh, f = arch["num_kv_heads"], arch["head_dim"], arch["d_ff"]
+    V = arch["vocab_size"]
+    layers = {
+        "pre_attn": ((L, d), 0), "pre_mlp": ((L, d), 0),
+        "attn": {"wq": ((L, d, H, dh), d), "wk": ((L, d, Hkv, dh), d),
+                 "wv": ((L, d, Hkv, dh), d), "wo": ((L, H, dh, d), H * dh)},
+        "mlp": {"up": ((L, d, f), d), "down": ((L, f, d), f)},
+    }
+    tree = {"embed": ((V, d), EMBED_FAN_IN), "final_norm": ((d,), 0),
+            "layers": layers}
+    if arch["attn_kind"] == "slay":
+        tree["slay"] = {"anchors": ((arch["slay_anchors"], dh), -1),
+                        "omegas": ((arch["slay_prf"], dh), -1)}
+    return tree
+
+
+def finish(params: dict, arch: dict) -> dict:
+    """The drawn tree made into SLAY's feature bank: anchors unit rows,
+    PRF omegas in antithetic pairs (omega, -omega)."""
+    if "slay" in params:
+        a = params["slay"]["anchors"]
+        params["slay"]["anchors"] = a / jnp.linalg.norm(a, axis=-1,
+                                                        keepdims=True)
+        om = params["slay"]["omegas"]
+        half = om.shape[0] // 2
+        params["slay"]["omegas"] = jnp.concatenate([om[:half], -om[:half]])
+    return params
 
 
 def quadrature(num_nodes: int, eps: float) -> tuple[np.ndarray, np.ndarray]:

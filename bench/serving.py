@@ -15,7 +15,6 @@ import jax
 import numpy as np
 
 from bench import generate
-from bench.reference import model as ref_model
 
 
 def arch_config(cfg: dict):
@@ -181,17 +180,19 @@ def pick_sample(rec: Recorder, seed: int, n: int) -> list[int]:
     return [longest] + [int(r) for r in pick]
 
 
-def served_gaps(params, cfg: dict, seqs, pad_to: int, n_out: int,
+def served_gaps(model, params, cfg: dict, seqs, pad_to: int, n_out: int,
                 control: str | None = None) -> dict:
     """Widest gap by which a served token's logit lies below the
     reference's best, over every served token of the sampled requests.
 
-    ``seqs``: [(prompt, served tokens)]. The reference runs once over each
-    prompt followed by its served tokens (teacher-forced), padded to one
-    length so it compiles once. With ``control`` (a lower precision) it
-    also gives the gap of the token that precision ranks first at each
-    position: the reading a program in that precision would give."""
-    fns = {prec: jax.jit(functools.partial(ref_model.logits, cfg=cfg,
+    ``model``: the configuration's model module, whose ``logits`` is the
+    reference. ``seqs``: [(prompt, served tokens)]. The reference runs
+    once over each prompt followed by its served tokens (teacher-forced),
+    padded to one length so it compiles once. With ``control`` (a lower
+    precision) it also gives the gap of the token that precision ranks
+    first at each position: the reading a program in that precision would
+    give."""
+    fns = {prec: jax.jit(functools.partial(model.logits, cfg=cfg,
                                            prec=prec))
            for prec in ("float32", control) if prec}
     worst, worst_c, n_tok = 0.0, 0.0, 0
@@ -255,8 +256,8 @@ def run_cell(cell, seed: int, seconds: float, tracing: bool, *,
     ``next_due()`` is the next send time or None."""
     import gc
 
+    from bench import spec, weights
     from bench import trace as trace_lib
-    from bench import weights
     from bench.result import Check, Outcome
 
     cfg, traffic = cell.config, cell.traffic
@@ -264,7 +265,8 @@ def run_cell(cell, seed: int, seconds: float, tracing: bool, *,
     clock = time.perf_counter
     split = {"process_start_s": round(clock() - t_start, 3)}
     a = clock()
-    params = weights.make(arch, seed)
+    model = spec.model(cfg)
+    params = weights.make(arch, seed, model)
     jax.block_until_ready(params)
     split["weights_s"] = round(clock() - a, 3)
     a = clock()
@@ -345,7 +347,7 @@ def run_cell(cell, seed: int, seconds: float, tracing: bool, *,
     seqs = [(rec.req[r].prompt, np.asarray(rec.tokens[r], np.int32))
             for r in sample]
     a = clock()
-    gaps = served_gaps(params, cfg, seqs, pad_to, n_out, control)
+    gaps = served_gaps(model, params, cfg, seqs, pad_to, n_out, control)
     split["reference_s"] = round(clock() - a, 3)
     checks = {
         "gap": Check(gaps["gap"], cell.limits["gap"],

@@ -4,13 +4,18 @@ Everything that belongs to one configuration, one traffic mix, one cell's
 correctness limits or one per-layer metric is a file of its own, found by
 the name BENCHMARK.json gives it:
 
-    bench/configs/<config>.json      model sizes as run
+    bench/configs/<config>.json      model sizes as run; its "model" key
+                                     names the model module
+    bench/reference/<family>.py      one model module per family: the
+                                     weights tree (shapes, finish) and
+                                     the plain reference (logits)
     bench/traffic/<traffic>.json     traffic mix; its "kind" picks the driver
     bench/drivers/<kind>.py          one driver per traffic kind
     bench/limits/<workload>.json     correctness limits of one cell
     bench/metrics/<metric>.py        one reader per per-layer metric
 
-A later change adds a cell or a metric by adding files and entries only.
+A later change adds a configuration, a cell or a metric by adding files
+and entries only.
 """
 from __future__ import annotations
 
@@ -93,9 +98,10 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
     else:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
     config = _load_json(config_path(w["config"], bench, root))
-    traffic = _load_json(os.path.join(BENCH_DIR, "traffic",
+    bench_dir = os.path.join(root, "bench")
+    traffic = _load_json(os.path.join(bench_dir, "traffic",
                                       w["traffic"] + ".json"))
-    limits = _load_json(os.path.join(BENCH_DIR, "limits",
+    limits = _load_json(os.path.join(bench_dir, "limits",
                                      workload + ".json"))
     e2e = tuple(m for m in map(_metric, bench["end_to_end"])
                 if m.applies_to(workload))
@@ -103,6 +109,22 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
                       if m.applies_to(workload))
     return Cell(workload, int(w["chips"]), config, traffic, limits, e2e,
                 per_layer)
+
+
+def model(cfg: dict, root: str = ROOT) -> ModuleType:
+    """The model module a configuration names under ``"model"``, a path
+    from the repository's root: ``shapes(arch)`` and ``finish(params,
+    arch)`` give the weights tree, ``logits(params, cfg, tokens, idx,
+    prec)`` the plain reference."""
+    if "model" not in cfg:
+        raise KeyError(f"configuration {cfg.get('name')!r} names no "
+                       f"\"model\" module")
+    path = os.path.join(root, cfg["model"])
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"configuration {cfg.get('name')!r} names "
+                                f"model {cfg['model']!r}, which is no file")
+    name = os.path.splitext(os.path.basename(path))[0]
+    return load_module(path, "bench_model_" + name.replace("-", "_"))
 
 
 def driver(kind: str) -> ModuleType:

@@ -104,7 +104,7 @@ def record(out_dir: str):
     cfg = json.load(open(spec.config_path("slayformer-124m",
                                           spec.load_benchmark())))
     cfg = dict(cfg, arch=dict(cfg["arch"], num_layers=LAYERS))
-    params = weights.make(cfg["arch"], SEED)
+    params = weights.make(cfg["arch"], SEED, spec.model(cfg))
     eng = serving.build_engine(cfg, params, POOL)
     schedule(eng, trace.Capture(False))         # compile everything
     m = eng.metrics

@@ -158,7 +158,7 @@ def test_weights_tree_is_the_programs(config):
     cfg = json.load(open(spec.config_path(config, BENCH)))
     arch = dict(cfg["arch"], num_layers=2, d_model=64, num_heads=4,
                 num_kv_heads=4, head_dim=16, d_ff=128, vocab_size=256)
-    ours = jax.eval_shape(lambda: weights.make(arch, 9))
+    ours = jax.eval_shape(lambda: weights.make(arch, 9, spec.model(cfg)))
     prog = api.abstract_params(serving.arch_config(dict(cfg, arch=arch)))
     assert jax.tree.structure(ours) == jax.tree.structure(prog)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(prog)):
@@ -167,12 +167,13 @@ def test_weights_tree_is_the_programs(config):
 
 def test_weights_are_a_function_of_the_seed():
     import jax
-    arch = dict(json.load(open(spec.config_path("slayformer-124m",
-                                                BENCH)))["arch"],
-                num_layers=1, d_model=32, num_heads=2, num_kv_heads=2,
-                head_dim=16, d_ff=64, vocab_size=64)
-    a, b = weights.make(arch, 2**33 + 1), weights.make(arch, 2**33 + 1)
-    c = weights.make(arch, 2**33 + 2)
+    cfg = json.load(open(spec.config_path("slayformer-124m", BENCH)))
+    arch = dict(cfg["arch"], num_layers=1, d_model=32, num_heads=2,
+                num_kv_heads=2, head_dim=16, d_ff=64, vocab_size=64)
+    model = spec.model(cfg)
+    a = weights.make(arch, 2**33 + 1, model)
+    b = weights.make(arch, 2**33 + 1, model)
+    c = weights.make(arch, 2**33 + 2, model)
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
     assert not np.array_equal(np.asarray(a["embed"]), np.asarray(c["embed"]))
