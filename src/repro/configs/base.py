@@ -65,6 +65,18 @@ class ArchConfig:
     slay_prf: int = 16
     slay_quad_nodes: int = 3
     chunk_size: int = 256
+    # Mixed layer stack: one mixer kind per layer, "mamba" (a Mamba-2 SSD
+    # block) or "attention" (exact GQA over a KV ring); () = the family's
+    # one kind in every layer. The keys below it belong to the mixed stack
+    # (granite-4.0-h's muP multipliers, NoPE, its norm eps) and keep their
+    # defaults elsewhere.
+    layer_types: tuple[str, ...] = ()
+    embedding_multiplier: float = 1.0  # embeddings x this
+    residual_multiplier: float = 1.0   # each branch x this before its add
+    logits_scaling: float = 1.0        # logits / this
+    attention_multiplier: float = 0.0  # score scale; 0 = 1/sqrt(head_dim)
+    position_embedding: str = "rope"   # rope | nope
+    norm_eps: float = 1e-6             # RMSNorm and Mamba-2 gated-norm eps
     # Pallas attention kernels (trainable — the kernels carry custom VJPs).
     # use_pallas dispatches the compiled kernels on TPU (jnp reference
     # elsewhere — kernels.ops picks by platform, so the CPU tests run the
@@ -76,6 +88,39 @@ class ArchConfig:
     dtype: str = "bfloat16"
     # Source provenance (public-literature citation)
     source: str = ""
+
+    def __post_init__(self):
+        # The config is a static jit argument, so it must hash: a list
+        # becomes a tuple, and a string (how a configuration file whose
+        # values are hashed, as bench/weights.py hashes them, gives it)
+        # is split at its commas. A stack cut in depth keeps the first
+        # num_layers layers of the pattern.
+        kinds = self.layer_types
+        if isinstance(kinds, str):
+            kinds = kinds.split(",") if kinds else ()
+        if kinds and len(kinds) < self.num_layers:
+            raise ValueError(f"layer_types has {len(kinds)} entries for "
+                             f"{self.num_layers} layers")
+        object.__setattr__(self, "layer_types",
+                           tuple(kinds[:self.num_layers]))
+        if set(self.layer_types) - {"mamba", "attention"}:
+            raise ValueError(f"layer_types holds only 'mamba' and "
+                             f"'attention', got {self.layer_types!r}")
+        if self.position_embedding not in ("rope", "nope"):
+            raise ValueError(f"position_embedding is rope or nope, got "
+                             f"{self.position_embedding!r}")
+        mixed_only = dict(embedding_multiplier=1.0, residual_multiplier=1.0,
+                          logits_scaling=1.0, attention_multiplier=0.0,
+                          position_embedding="rope", norm_eps=1e-6)
+        if not self.layer_types:
+            off = [k for k, v in mixed_only.items() if getattr(self, k) != v]
+            if off:
+                raise ValueError(f"{off} apply to a mixed stack only: set "
+                                 f"layer_types")
+        elif self.family != "decoder" or self.moe_experts or self.frontend \
+                or self.local_window or self.attn_kind != "softmax":
+            raise ValueError("a mixed stack is a dense decoder of Mamba-2 "
+                             "and softmax attention layers")
 
     @property
     def activation_dtype(self):
@@ -94,6 +139,9 @@ class ArchConfig:
 
     def attention_spec(self, *, local: bool = False) -> AttentionSpec:
         """The AttentionSpec for a (global|local) layer under this config."""
+        if self.layer_types:
+            return AttentionSpec(kind="softmax", chunk_size=self.chunk_size,
+                                 scale=self.attention_multiplier)
         if local and self.local_window:
             return AttentionSpec(kind="softmax", window=self.local_window,
                                  logit_softcap=self.attn_logit_softcap,

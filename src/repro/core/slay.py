@@ -91,6 +91,7 @@ class AttentionSpec:
     slay: SlayConfig | None = None
     window: int = 0            # sliding window for local softmax layers
     logit_softcap: float = 0.0
+    scale: float = 0.0         # exact score scale; 0 = 1/sqrt(head_dim)
     chunk_size: int = 256
     use_pallas: bool = True
     # With use_pallas: run the end-to-end megakernel (Ψ fused into the

@@ -214,8 +214,8 @@ def prefill_chunk(spec: AttentionSpec, params: dict | None, q, k, v,
         den = jnp.sum(scores, axis=-1)[..., None] + 1e-6
         y = (num / den).reshape(B, Lc, hkv * g, v.shape[-1])
     else:
-        scores = jnp.einsum("blkgd,bskd->blkgs", qg, k_all) / jnp.sqrt(
-            jnp.asarray(dh, q.dtype))
+        scores = _scaled(spec, jnp.einsum("blkgd,bskd->blkgs", qg, k_all),
+                         dh)
         if spec.logit_softcap:
             scores = spec.logit_softcap * jnp.tanh(
                 scores / spec.logit_softcap)
@@ -234,6 +234,14 @@ def prefill_chunk(spec: AttentionSpec, params: dict | None, q, k, v,
     kbuf = cache.k.at[b, idx].set(k[:, Lc - take:].astype(cache.k.dtype))
     vbuf = cache.v.at[b, idx].set(v[:, Lc - take:].astype(cache.v.dtype))
     return y, AttnCache(kbuf, vbuf, start + Lc, None, None)
+
+
+def _scaled(spec: AttentionSpec, scores, dh: int):
+    """Softmax scores times the spec's scale, or over sqrt(dh) when it
+    sets none."""
+    if spec.scale:
+        return scores * jnp.asarray(spec.scale, scores.dtype)
+    return scores / jnp.sqrt(jnp.asarray(dh, scores.dtype))
 
 
 def _yat_scores(kind: str, qg, kb):
@@ -348,8 +356,7 @@ def decode_step(spec: AttentionSpec, params: dict | None, q, k, v,
             y = jnp.where(act[:, None, None], y, 0).astype(y.dtype)
         return y, AttnCache(kbuf, vbuf, n_seen, None, None)
 
-    logits = jnp.einsum("...kgd,...skd->...kgs", qg, kb) / jnp.sqrt(
-        jnp.asarray(dh, q.dtype))
+    logits = _scaled(spec, jnp.einsum("...kgd,...skd->...kgs", qg, kb), dh)
     if spec.logit_softcap:
         logits = spec.logit_softcap * jnp.tanh(logits / spec.logit_softcap)
     logits = jnp.where(valid, logits, jnp.finfo(logits.dtype).min)

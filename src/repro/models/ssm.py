@@ -95,7 +95,7 @@ def ssd_forward(params: dict, x: jnp.ndarray, *, d_state: int,
 def ssd_prefill_chunk(params: dict, x: jnp.ndarray, state: SsmState, *,
                       d_state: int, expand: int = 2, head_dim: int = 64,
                       ngroups: int = 1, conv_width: int = 4,
-                      chunk_size: int = 256
+                      chunk_size: int = 256, norm_eps: float = 1e-6
                       ) -> tuple[jnp.ndarray, SsmState]:
     """Absorb an arbitrary-length prompt chunk into an ``SsmState``.
 
@@ -137,7 +137,7 @@ def ssd_prefill_chunk(params: dict, x: jnp.ndarray, state: SsmState, *,
     y = y * jax.nn.silu(z)
     yf = y.astype(jnp.float32)
     var = jnp.mean(jnp.square(yf), -1, keepdims=True)
-    y = (yf * jax.lax.rsqrt(var + 1e-6)
+    y = (yf * jax.lax.rsqrt(var + norm_eps)
          * (1.0 + params["norm"].astype(jnp.float32))).astype(x.dtype)
     return y @ params["out_proj"], SsmState(h, tail.astype(jnp.float32))
 
@@ -224,7 +224,8 @@ def ssd_init_state(lead_shape, d_model: int, d_state: int, expand: int = 2,
 
 def ssd_decode_step(params: dict, x: jnp.ndarray, state: SsmState, *,
                     d_state: int, expand: int = 2, head_dim: int = 64,
-                    ngroups: int = 1, conv_width: int = 4):
+                    ngroups: int = 1, conv_width: int = 4,
+                    norm_eps: float = 1e-6):
     """One token. x (B, d_model) -> (B, d_model), O(nh*hd*ds) state update."""
     d_model = x.shape[-1]
     z, xs, b, c, dt, d_inner, nheads = _split_proj(
@@ -253,6 +254,6 @@ def ssd_decode_step(params: dict, x: jnp.ndarray, state: SsmState, *,
     y = y.reshape(B, d_inner).astype(x.dtype) * jax.nn.silu(z)
     yf = y.astype(jnp.float32)
     var = jnp.mean(jnp.square(yf), -1, keepdims=True)
-    y = (yf * jax.lax.rsqrt(var + 1e-6)
+    y = (yf * jax.lax.rsqrt(var + norm_eps)
          * (1.0 + params["norm"].astype(jnp.float32))).astype(x.dtype)
     return y @ params["out_proj"], SsmState(h, hist[..., 1:, :])

@@ -14,6 +14,7 @@ swapped in via config without touching model code.
 """
 from __future__ import annotations
 
+import itertools
 from typing import NamedTuple
 
 import jax
@@ -57,6 +58,11 @@ def attn_proj_specs(cfg: ArchConfig) -> dict:
 
 def layer_specs(cfg: ArchConfig) -> dict:
     d = cfg.d_model
+    if cfg.layer_types:
+        # What every layer of a mixed stack has; its mixer's weights are
+        # stacked by kind (_mixed_specs).
+        return {"pre_mix": rmsnorm_spec(d), "pre_mlp": rmsnorm_spec(d),
+                "mlp": mlp_specs(d, cfg.d_ff, cfg.gated_mlp)}
     if cfg.family == "ssm":
         return {"pre": rmsnorm_spec(d),
                 "ssd": ssm.ssd_specs(d, cfg.ssm_state, cfg.ssm_expand,
@@ -81,6 +87,8 @@ def model_specs(cfg: ArchConfig) -> dict:
         "final_norm": rmsnorm_spec(cfg.d_model),
         "layers": stack_specs(layer_specs(cfg), cfg.num_layers),
     }
+    if cfg.layer_types:
+        specs.update(_mixed_specs(cfg))
     if not cfg.tie_embeddings:
         specs["unembed"] = ParamSpec((cfg.vocab_size, cfg.d_model),
                                      ("vocab", "embed"), scale=1.0)
@@ -193,6 +201,8 @@ def forward(params: dict, cfg: ArchConfig, tokens: jnp.ndarray, *,
     """tokens (B, Lt) -> logits (B, L, V), aux loss. Vision prefix embeds
     (B, P, d) are concatenated ahead of the token embeddings (stub frontend).
     """
+    if cfg.layer_types:
+        return _mixed_forward(params, cfg, tokens)
     x = embed(params["embed"], tokens).astype(cfg.activation_dtype)
     if patch_embeds is not None:
         x = jnp.concatenate([patch_embeds.astype(x.dtype), x], axis=1)
@@ -292,7 +302,7 @@ def supports_paging(cfg: ArchConfig) -> bool:
     they bypass paging entirely; windowed rings are already bounded by the
     window and wrap in place.
     """
-    if cfg.family in ("ssm", "hybrid", "encdec"):
+    if cfg.family in ("ssm", "hybrid", "encdec") or cfg.layer_types:
         return False
     if cfg.local_window or cfg.frontend:
         return False
@@ -308,7 +318,8 @@ def context_capacity(cfg: ArchConfig, max_len: int) -> int | None:
     its ``max_len`` allocation. This is what lets oversized linear-vision
     prompts admit (absorbed chunk-by-chunk) instead of being rejected.
     """
-    if cfg.family == "ssm":
+    if cfg.family == "ssm" or (cfg.layer_types
+                               and "attention" not in cfg.layer_types):
         return None
     if cfg.attention_spec().is_linear or cfg.local_window:
         return None
@@ -325,6 +336,8 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, *,
     page_size, Hkv, dh)`` physical pages shared by all ``batch`` slots —
     and a fresh all-free ``PageState`` rides in ``cache.pages``.
     """
+    if cfg.layer_types:
+        return _mixed_init_cache(cfg, batch, max_len)
     nl = cfg.num_layers
     dh = cfg.resolved_head_dim
     dtype = cfg.activation_dtype
@@ -401,8 +414,11 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
     whole layer stack, not its scanned ``xs``/``ys``: each layer updates
     its own slice in place (``attention.decode_step(layer=...)``), so the
     pool's state is never sliced out, written back or copied per tick.
-    KV rings and SSM carries, one layer's at a time, stay scanned.
+    KV rings and SSM carries, one layer's at a time, stay scanned; a
+    mixed stack carries both in place (:func:`_mixed_decode_step`).
     """
+    if cfg.layer_types:
+        return _mixed_decode_step(params, cfg, cache, tokens, active)
     x = embed(params["embed"], tokens[:, 0]).astype(cfg.activation_dtype)
     pos = cache.pos
     act = None if active is None else active.astype(bool)
@@ -526,7 +542,7 @@ def supports_masked_prefill(cfg: ArchConfig) -> bool:
     fall back to per-length compilation.
     """
     return cfg.family not in ("ssm", "hybrid", "encdec") \
-        and not cfg.local_window
+        and not cfg.local_window and not cfg.layer_types
 
 
 def prefill(params: dict, cfg: ArchConfig, tokens: jnp.ndarray, *,
@@ -552,6 +568,10 @@ def prefill(params: dict, cfg: ArchConfig, tokens: jnp.ndarray, *,
         raise NotImplementedError(
             f"true_len-masked prefill unsupported for {cfg.name} "
             f"(family={cfg.family}, local_window={cfg.local_window})")
+    if cfg.layer_types:
+        cache = init_cache(cfg, tokens.shape[0],
+                           max_len if max_len else tokens.shape[1] + 64)
+        return prefill_chunk(params, cfg, cache, tokens)
     B = tokens.shape[0]
     x = embed(params["embed"], tokens).astype(cfg.activation_dtype)
     if patch_embeds is not None:
@@ -788,6 +808,10 @@ def prefill_chunk(params: dict, cfg: ArchConfig, cache: DecodeCache,
     (``tokens`` is ignored when given). The continuation is position-
     driven, so prefix-embed chunks and token chunks interleave exactly.
     """
+    if cfg.layer_types:
+        x, cache = _mixed_chunk(params, cfg, cache,
+                                _mixed_embed(params, cfg, tokens))
+        return _mixed_logits(params, cfg, x[:, -1])[:, None, :], cache
     if embeds is not None:
         x = embeds.astype(cfg.activation_dtype)
         B, Lc = x.shape[0], x.shape[1]
@@ -882,7 +906,8 @@ def supports_speculative(cfg: ArchConfig) -> bool:
     which rules out encdec and modality frontends. Windowed/mixed-window
     rings are excluded with (1): an in-window eviction is not rewindable.
     """
-    if cfg.family in ("ssm", "hybrid", "encdec") or cfg.frontend:
+    if cfg.family in ("ssm", "hybrid", "encdec") or cfg.frontend \
+            or cfg.layer_types:
         return False
     if cfg.local_window or cfg.local_global_period:
         return False
@@ -1025,3 +1050,239 @@ def _ssd_prefill_state(cfg: ArchConfig, lp: dict, xn: jnp.ndarray):
         full, L - (cfg.ssm_conv_width - 1), cfg.ssm_conv_width - 1,
         axis=1).astype(jnp.float32)            # (B, W-1, conv_dim)
     return ssm.SsmState(h, conv)
+
+
+# ---------------------------------------------------------------------------
+# Mixed stack: Mamba-2 and attention layers (cfg.layer_types)
+# ---------------------------------------------------------------------------
+#
+# Every layer is pre-norm mixer, residual, pre-norm gated MLP, residual,
+# with each branch scaled by ``residual_multiplier`` before its add
+# (granite-4.0-h). The mixer is a Mamba-2 SSD block or GQA softmax
+# attention. Mixer weights and decode state are stacked by kind, so a
+# model of 36 Mamba and 4 attention layers holds 4 KV rings, not 40.
+#
+# The layer pattern runs as alternating runs of each kind: a scan over
+# (Mamba run, attention run) pairs, each run a loop of its kind's layer
+# body with the run's length as its trip count. The program holds one
+# body of each kind however the kinds interleave, and no branch between
+# them: under ``lax.cond`` the branch that leaves a stack as it is copies
+# it whole (XLA copies a conditional's passed-through operands), which at
+# 64 slots is a 4.8 GB copy of the SSM state per attention layer. Each
+# body reads its kind's weights and state at the layer's index into that
+# kind's stack and writes its state back there in place.
+
+
+def _mixed_counts(cfg: ArchConfig) -> tuple[int, int]:
+    n_attn = cfg.layer_types.count("attention")
+    return cfg.num_layers - n_attn, n_attn
+
+
+def _mixed_runs(cfg: ArchConfig) -> dict:
+    """The layer pattern as pairs of runs, a run of Mamba layers then a
+    run of attention layers, either possibly empty: per pair the lengths
+    and where each run starts in the layer stack and in its kind's
+    stack."""
+    pairs: list[list[int]] = []           # [Mamba run, attention run]
+    for is_attn, run in itertools.groupby(t == "attention"
+                                          for t in cfg.layer_types):
+        n = len(list(run))
+        if not is_attn:
+            pairs.append([n, 0])
+        elif pairs and not pairs[-1][1]:
+            pairs[-1][1] = n
+        else:
+            pairs.append([0, n])
+    m_len, a_len = (list(x) for x in zip(*pairs))
+
+    def start(n):
+        return [0, *itertools.accumulate(n)][:-1]
+
+    return {"m_len": m_len, "a_len": a_len, "m0": start(m_len),
+            "a0": start(a_len),
+            "l0": start([m + a for m, a in pairs])}
+
+
+def _mixed_specs(cfg: ArchConfig) -> dict:
+    n_mamba, n_attn = _mixed_counts(cfg)
+    specs = {}
+    if n_mamba:
+        specs["ssd"] = stack_specs(ssm.ssd_specs(
+            cfg.d_model, cfg.ssm_state, cfg.ssm_expand, cfg.ssm_head_dim,
+            cfg.ssm_ngroups, cfg.ssm_conv_width), n_mamba)
+    if n_attn:
+        specs["attn"] = stack_specs(attn_proj_specs(cfg), n_attn)
+    return specs
+
+
+def _mixed_init_cache(cfg: ArchConfig, batch: int,
+                      max_len: int) -> DecodeCache:
+    """SSM state and conv tails for the Mamba layers, a KV ring for each
+    attention layer, each stacked over its own kind's layers."""
+    n_mamba, n_attn = _mixed_counts(cfg)
+    s_cache = a_cache = None
+    if n_mamba:
+        s_cache = ssm.ssd_init_state((n_mamba, batch), cfg.d_model,
+                                     cfg.ssm_state, cfg.ssm_expand,
+                                     cfg.ssm_head_dim, cfg.ssm_ngroups,
+                                     cfg.ssm_conv_width)
+    if n_attn:
+        ring = (n_attn, batch, max_len, cfg.num_kv_heads,
+                cfg.resolved_head_dim)
+        a_cache = attn.AttnCache(
+            jnp.zeros(ring, cfg.activation_dtype),
+            jnp.zeros(ring, cfg.activation_dtype),
+            jnp.zeros((n_attn, batch), jnp.int32), None, None)
+    return DecodeCache(a_cache, s_cache, jnp.zeros((batch,), jnp.int32))
+
+
+def _mixed_embed(params: dict, cfg: ArchConfig, tokens) -> jnp.ndarray:
+    x = embed(params["embed"], tokens).astype(cfg.activation_dtype)
+    return x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+
+
+def _mixed_logits(params: dict, cfg: ArchConfig, x) -> jnp.ndarray:
+    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    logits = unembed(params.get("unembed", params["embed"]), x)
+    return logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
+
+
+def _at(tree, i):
+    """Entry ``i`` of every leaf of a stacked tree."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
+
+
+def _put(tree, new, i):
+    """Write ``new`` as entry ``i`` of every leaf of a stacked tree."""
+    return jax.tree.map(
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, i, 0),
+        tree, new)
+
+
+def _ssd_kw(cfg: ArchConfig) -> dict:
+    return dict(d_state=cfg.ssm_state, expand=cfg.ssm_expand,
+                head_dim=cfg.ssm_head_dim, ngroups=cfg.ssm_ngroups,
+                conv_width=cfg.ssm_conv_width, norm_eps=cfg.norm_eps)
+
+
+def _qkv(cfg: ArchConfig, p: dict, h, positions):
+    """GQA projections of normed input h (..., L, d); RoPE unless NoPE."""
+    q = jnp.einsum("...d,dhk->...hk", h, p["wq"])
+    k = jnp.einsum("...d,dhk->...hk", h, p["wk"])
+    v = jnp.einsum("...d,dhk->...hk", h, p["wv"])
+    if cfg.position_embedding == "rope":
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def _mixed_layers(params: dict, cfg: ArchConfig, x, cache: DecodeCache,
+                  mamba, attention):
+    """Run x (B, ..., d) through every layer of a mixed stack, both kinds'
+    stacked state riding the carry. ``mamba(j, h, ssm_state)`` and
+    ``attention(j, h, attn_cache)`` give the mixer output for the normed
+    input h and their kind's stacks with entry j updated."""
+    rm = cfg.residual_multiplier
+
+    def layer(mixer, state_at):
+        def body(i, carry):
+            l, j = i                      # layer index, index in its kind
+            x, st = carry
+            lp = _at(params["layers"], l)
+            y, new = mixer(j, rmsnorm(lp["pre_mix"], x, cfg.norm_eps),
+                           st[state_at])
+            st = tuple(new if n == state_at else s for n, s in enumerate(st))
+            x = x + y * jnp.asarray(rm, y.dtype)
+            y = mlp(lp["mlp"], rmsnorm(lp["pre_mlp"], x, cfg.norm_eps),
+                    cfg.gated_mlp)
+            return x + y * jnp.asarray(rm, y.dtype), st
+        return body
+
+    mamba_layer, attn_layer = layer(mamba, 0), layer(attention, 1)
+
+    def pair(carry, r):
+        if cache.ssm is not None:
+            carry = jax.lax.fori_loop(
+                0, r["m_len"], lambda i, c: mamba_layer(
+                    (r["l0"] + i, r["m0"] + i), c), carry)
+        if cache.attn is not None:
+            carry = jax.lax.fori_loop(
+                0, r["a_len"], lambda i, c: attn_layer(
+                    (r["l0"] + r["m_len"] + i, r["a0"] + i), c), carry)
+        return carry, None
+
+    runs = {k: jnp.asarray(v, jnp.int32) for k, v in _mixed_runs(cfg).items()}
+    (x, (st, ac)), _ = jax.lax.scan(pair, (x, (cache.ssm, cache.attn)), runs)
+    return x, st, ac
+
+
+def _mixed_chunk(params: dict, cfg: ArchConfig, cache: DecodeCache, x):
+    """Absorb a chunk x (B, Lc, d) into a mixed stack's cache: the Mamba
+    layers continue their SSD scan and conv tails
+    (``ssm.ssd_prefill_chunk``), the attention layers attend their ring
+    prefix and the chunk (``attention.prefill_chunk``). Returns every
+    position's hidden state and the advanced cache."""
+    Lc = x.shape[1]
+    positions = cache.pos[:, None] + jnp.arange(Lc, dtype=jnp.int32)[None, :]
+    spec = cfg.attention_spec()
+
+    def mamba(j, h, st):
+        y, new = ssm.ssd_prefill_chunk(
+            _at(params["ssd"], j), h, _at(st, j),
+            chunk_size=max(min(cfg.chunk_size, Lc), 1), **_ssd_kw(cfg))
+        return y, _put(st, new, j)
+
+    def attention(j, h, ac):
+        p = _at(params["attn"], j)
+        q, k, v = _qkv(cfg, p, h, positions)
+        y, new = attn.prefill_chunk(spec, None, q, k, v, _at(ac, j))
+        return jnp.einsum("blhk,hkd->bld", y, p["wo"]), _put(ac, new, j)
+
+    x, st, ac = _mixed_layers(params, cfg, x, cache, mamba, attention)
+    return x, DecodeCache(ac, st, cache.pos + Lc, cache.pages)
+
+
+def _mixed_forward(params: dict, cfg: ArchConfig, tokens):
+    """Full-sequence logits of a mixed stack: the chunk continuation from
+    an empty cache, over the whole sequence. The layer runs loop with
+    traced trip counts, so there is no reverse-mode gradient: the mixed
+    stack serves and has no training path."""
+    B, L = tokens.shape
+    x, _ = _mixed_chunk(params, cfg, init_cache(cfg, B, L),
+                        _mixed_embed(params, cfg, tokens))
+    return _mixed_logits(params, cfg, x), jnp.zeros((), jnp.float32)
+
+
+def _mixed_decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
+                       tokens, active):
+    """One decode tick of a mixed stack. The SSM state, conv tails and KV
+    rings of every layer ride the layer loops' carry; each layer reads and
+    writes its own entry of its kind's stack in place, so the pool's state
+    is never scanned through, written back whole or copied. Drained slots
+    keep their bytes (``active`` masks the writes) and add nothing."""
+    x = _mixed_embed(params, cfg, tokens[:, 0])
+    act = None if active is None else active.astype(bool)
+    spec = cfg.attention_spec()
+    pos = cache.pos
+
+    def mamba(j, h, st):
+        old = _at(st, j)
+        y, new = ssm.ssd_decode_step(_at(params["ssd"], j), h, old,
+                                     **_ssd_kw(cfg))
+        if act is not None:
+            new = _state_passthrough(new, old, act)
+            y = jnp.where(act[:, None], y, 0).astype(y.dtype)
+        return y, _put(st, new, j)
+
+    def attention(j, h, ac):
+        p = _at(params["attn"], j)
+        q, k, v = _qkv(cfg, p, h[:, None], pos[:, None])
+        y, new = attn.decode_step(spec, None, q[:, 0], k[:, 0], v[:, 0],
+                                  _at(ac, j), active=act)
+        return jnp.einsum("bhk,hkd->bd", y, p["wo"]), _put(ac, new, j)
+
+    x, st, ac = _mixed_layers(params, cfg, x, cache, mamba, attention)
+    step = 1 if act is None else act.astype(jnp.int32)
+    return (_mixed_logits(params, cfg, x)[:, None, :],
+            DecodeCache(ac, st, pos + step, cache.pages))

@@ -325,6 +325,23 @@ def _macro_decode(params, cache, last_tok, active, rids, gen, eos_ids,
     return cache, toks, em, flt
 
 
+def pool_bytes(cache) -> dict:
+    """Bytes of a pooled decode cache (arrays or shapes) by kind of state:
+    ``ssm_state`` and ``conv_tails`` (Mamba carries), ``kv_rings``,
+    ``linear_state`` (the linear kinds' (S, z)); kinds it lacks are left
+    out."""
+    a, m = getattr(cache, "attn", None), getattr(cache, "ssm", None)
+    parts = {"ssm_state": m and m.h, "conv_tails": m and m.conv,
+             "kv_rings": a and (a.k, a.v), "linear_state": a and (a.s, a.z)}
+    out = {}
+    for kind, tree in parts.items():
+        n = sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
+                for x in jax.tree.leaves(tree))
+        if n:
+            out[kind] = n
+    return out
+
+
 def _bucket_len(n: int, lo: int, cap: int) -> int:
     """Smallest pow-2 >= max(n, lo), capped at ``cap`` (>= n always)."""
     b = lo
@@ -440,6 +457,10 @@ class ServingMetrics:
     spec_gamma: int = 0         # draft tokens per round (0 = non-spec)
     draft_tokens_proposed: int = 0  # draft tokens offered to the verifier
     draft_tokens_accepted: int = 0  # draft tokens accepted
+    # Device bytes of the slot pool by kind of decode state, from its
+    # shapes at construction (pool_bytes): what the pool adds to the
+    # weights in the device's memory.
+    pool_bytes: dict = dataclasses.field(default_factory=dict)
     # Injectable time source (satellite of DESIGN.md §12): every wall-
     # clock read in the engine goes through this, so deadline tests use a
     # fake clock and journal timestamps are replayable.
@@ -508,6 +529,7 @@ class ServingMetrics:
             "fault_retries_succeeded": self.fault_retries_succeeded,
             "tokens_replayed": self.tokens_replayed,
             "checkpoints_written": self.checkpoints_written,
+            "pool_bytes": dict(self.pool_bytes),
             "wall_s": wall,
             "decode_tokens_per_s": self.tokens_generated / wall,
             "total_tokens_per_s":
@@ -881,6 +903,7 @@ class ContinuousServingEngine:
                                   if self._paged else 0),
                        shards=self.slot_shards)
         c_abs = api.abstract_cache(cfg, S, L, **page_kw)
+        self.metrics.pool_bytes = pool_bytes(c_abs)
         c_sh = shd.serving_cache_sharding(
             mesh, rules, c_abs, num_slots=S,
             slot_shards=serving.slot_shards,

@@ -145,3 +145,59 @@ def test_macro_decode_moves_pool_state_only_in_kernel(one_chip, monkeypatch):
            and (hlo.base_opcode(i.opcode) in moves
                 or (i.opcode == "fusion" and any(m in i.name for m in moves)))]
     assert not bad, "\n".join(bad)
+
+
+def test_mixed_macro_decode_updates_state_stacks_in_place(one_chip,
+                                                          monkeypatch):
+    """granite-4.0-h-micro's widths over six layers [M, M, A, M, A, M]
+    (K = 2 ticks, the cell's 64 slots of 2048-row rings): the serving
+    macro-step updates the SSM state stack and the K and V ring stacks in
+    place. No copy produces either stack, nor one layer of it: each
+    layer's body reads and writes its own entry of its kind's stack,
+    riding the loop carry. (Under ``lax.cond`` between the two bodies,
+    the branch that passes a stack through copies it whole.)"""
+    import json
+
+    from bench import serving, spec
+    from repro.analysis import hlo
+    from repro.kernels import ops
+    from repro.models import api
+    from repro.serving import engine
+
+    slots, max_len = 64, 2048
+    with open(spec.config_path("granite-4.0-h-micro",
+                               spec.load_benchmark())) as f:
+        cfg = json.load(f)
+    cfg["arch"].update(num_layers=6, layer_types="mamba,mamba,attention,"
+                       "mamba,attention,mamba")
+    cfg = serving.arch_config(cfg)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = placed(api.abstract_params(cfg))
+    cache = placed(jax.eval_shape(lambda: api.init_cache(cfg, slots,
+                                                         max_len)))
+    vectors = [jax.ShapeDtypeStruct((slots,), dt, sharding=one_chip)
+               for dt in (jnp.int32, jnp.bool_, jnp.int32, jnp.int32,
+                          jnp.int32, jnp.int32)]
+
+    def macro(params, cache, *vectors):
+        return engine._macro_decode(params, cache, *vectors, cfg=cfg,
+                                    num_ticks=2, temperature=0.0, seed=0)
+
+    text = jax.jit(macro, donate_argnums=(1,)).lower(
+        params, cache, *vectors).compile().as_text()
+    names = {jnp.dtype(jnp.float32): "f32", jnp.dtype(jnp.bfloat16): "bf16"}
+    stacks = set()
+    for leaf in (cache.ssm.h, cache.attn.k, cache.attn.v):
+        s = leaf.shape
+        for shape in (s, (1, *s[1:]), s[1:]):
+            stacks.add(f"{names[leaf.dtype]}[{','.join(map(str, shape))}]")
+    copies = [i.text[:160] for i in hlo.parse_hlo(text).instructions
+              if i.shape.split("{")[0] in stacks
+              and (hlo.base_opcode(i.opcode) == "copy"
+                   or (i.opcode == "fusion" and "copy" in i.name))]
+    assert not copies, "\n".join(copies)
