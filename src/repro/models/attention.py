@@ -22,12 +22,15 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
+from jax.sharding import PartitionSpec as P
 
 from repro.core import baselines as bl
 from repro.core import kernels as exact
 from repro.core import linear_attention as la
 from repro.core import slay as slay_mod
 from repro.core.slay import AttentionSpec
+from repro.distributed import sharding as shd
 
 
 class AttnCache(NamedTuple):
@@ -274,10 +277,16 @@ def decode_step(spec: AttentionSpec, params: dict | None, q, k, v,
     reference path and the kernel path are interchangeable mid-stream.
     Requires per-slot (vector) ``pos`` when given.
 
-    ``layer`` (int32 scalar, linear kinds): ``cache.s``/``cache.z`` hold
-    the state of every layer, stacked on a leading axis, and only layer
-    ``layer`` is read and updated, in place; the returned cache holds the
-    whole stacks. KV kinds ignore it (their leaves are one layer's).
+    ``layer`` (int32 scalar): the cache's state leaves hold every layer,
+    stacked on a leading axis — ``cache.s``/``cache.z`` for linear kinds,
+    the K and V rings ``(nl, B, S, Hkv, dh)`` for KV kinds — and only
+    layer ``layer`` is read and updated, in place; the returned cache
+    holds the whole stacks. Without it the leaves are one layer's.
+
+    KV kinds with per-slot ``pos`` write exactly one row per live slot,
+    at ``(layer, b, pos[b] % S)``, in place (:func:`_write_rows`); a
+    drained slot keeps its bytes. Attention then reads the layer's ring
+    from the stack, with no slice of it materialised.
     """
     act = None
     if active is not None:
@@ -314,29 +323,28 @@ def decode_step(spec: AttentionSpec, params: dict | None, q, k, v,
     ring = cache.pos % size
     n_seen = cache.pos + (1 if act is None else act.astype(jnp.int32))
     if cache.pos.ndim:
-        # Per-slot positions (continuous batching): each batch row writes
-        # its own ring slot and carries its own validity horizon. The
-        # write is a one-hot row select rather than a batch-indexed
-        # scatter: elementwise along the slot dim, it partitions cleanly
-        # when the pool is slot-sharded (a scatter with explicit batch
-        # indices forces GSPMD into all-gather/all-reduce — DESIGN.md §8),
-        # and the ring is already fully read by attention each tick, so
-        # bandwidth stays O(ring). Drained slots simply don't write.
-        kw = k.astype(cache.k.dtype)
-        vw = v.astype(cache.v.dtype)
-        write = jnp.arange(size)[None, :] == ring[:, None]       # (B, S)
-        if act is not None:
-            write = write & act[:, None]
-        wmask = write[:, :, None, None]               # vs (B, S, Hkv, dh)
-        kbuf = jnp.where(wmask, kw[:, None], cache.k)
-        vbuf = jnp.where(wmask, vw[:, None], cache.v)
+        # Per-slot positions (continuous batching): each live slot writes
+        # one row, at its own ring position, in place, and carries its own
+        # validity horizon. A one-layer ring is a stack of one.
+        li = jnp.int32(0) if layer is None else layer
+        kst, vst = (cache.k, cache.v) if layer is not None else (
+            cache.k[None], cache.v[None])
+        kst = _write_rows(kst, k, li, ring, act)
+        vst = _write_rows(vst, v, li, ring, act)
+        kbuf = jax.lax.dynamic_index_in_dim(kst, li, 0, keepdims=False)
+        vbuf = jax.lax.dynamic_index_in_dim(vst, li, 0, keepdims=False)
+        if layer is None:
+            kst, vst = kst[0], vst[0]
         valid = (jnp.arange(size)[None, :]
                  < jnp.minimum(n_seen, size)[:, None])    # (B, S)
         valid = valid[:, None, None, :]                   # vs (B,Hkv,G,S)
     else:
-        kbuf = jax.lax.dynamic_update_index_in_dim(
+        if layer is not None:
+            raise ValueError("a layer-stacked ring requires per-slot "
+                             "cache.pos")
+        kst = kbuf = jax.lax.dynamic_update_index_in_dim(
             cache.k, k.astype(cache.k.dtype), ring, axis=-3)
-        vbuf = jax.lax.dynamic_update_index_in_dim(
+        vst = vbuf = jax.lax.dynamic_update_index_in_dim(
             cache.v, v.astype(cache.v.dtype), ring, axis=-3)
         # Validity mask: ring slots written so far (inside the window).
         valid = jnp.arange(size) < jnp.minimum(n_seen, size)
@@ -354,7 +362,7 @@ def decode_step(spec: AttentionSpec, params: dict | None, q, k, v,
         y = (num / den).reshape(*q.shape[:-1], dv)
         if act is not None:
             y = jnp.where(act[:, None, None], y, 0).astype(y.dtype)
-        return y, AttnCache(kbuf, vbuf, n_seen, None, None)
+        return y, AttnCache(kst, vst, n_seen, None, None)
 
     logits = _scaled(spec, jnp.einsum("...kgd,...skd->...kgs", qg, kb), dh)
     if spec.logit_softcap:
@@ -365,7 +373,52 @@ def decode_step(spec: AttentionSpec, params: dict | None, q, k, v,
     y = y.reshape(*q.shape[:-1], dv)
     if act is not None:
         y = jnp.where(act[:, None, None], y, 0).astype(y.dtype)
-    return y, AttnCache(kbuf, vbuf, n_seen, None, None)
+    return y, AttnCache(kst, vst, n_seen, None, None)
+
+
+# The ring stacks' layout: ring rows on the minor (lane) dim. A (Hkv, dh)
+# row of (12, 64) bf16 pads to a (16, 128) tile when it is minor (2.67x
+# the bytes); with the rows on lanes nothing pads, and it is the layout the
+# TPU runtime gives the pool, so the macro-step relays nothing out.
+_RING_LAYOUT = Layout((0, 1, 3, 4, 2))
+
+
+def _write_rows(stack, rows, layer, at, live):
+    """``stack`` (nl, B, S, Hkv, d) with row ``at[b]`` of slot b's ring in
+    layer ``layer`` set to ``rows[b]`` (B, Hkv, d), in place, for each
+    slot whose ``live[b]`` holds (every slot when None); the others keep
+    their bytes.
+
+    One dynamic update of one row per slot, in the ring layout above. A
+    scatter would write all rows at once but, on a TPU, only with (Hkv, d)
+    minor: the compiler then relays the whole stack out around it. Under
+    a slot-sharded pool (``sharding.current_slot_pool``) the writes run
+    once per shard under ``shard_map`` with slot-local indices: GSPMD
+    would turn writes at explicit slot indices into collectives
+    (DESIGN.md §8)."""
+    def put(stack, rows, layer, at, live):
+        stack = with_layout_constraint(stack, _RING_LAYOUT)
+        rows = rows.astype(stack.dtype)[:, None, None, None]
+        for b in range(rows.shape[0]):
+            idx = (layer, b, at[b], 0, 0)
+            new = rows[b]
+            if live is not None:
+                old = jax.lax.dynamic_slice(stack, idx, new.shape)
+                new = jnp.where(live[b], new, old)
+            stack = jax.lax.dynamic_update_slice(stack, new, idx)
+        return with_layout_constraint(stack, _RING_LAYOUT)
+
+    pool = shd.current_slot_pool()
+    if pool is None or not pool[1]:
+        return put(stack, rows, layer, at, live)
+    mesh, axes = pool
+    row = P(axes[0] if len(axes) == 1 else axes)
+    return jax.shard_map(
+        put, mesh=mesh,
+        in_specs=(P(None, *row), row, P(), row,
+                  None if live is None else row),
+        out_specs=P(None, *row), check_vma=False)(stack, rows, layer, at,
+                                                   live)
 
 
 def _features(spec: AttentionSpec, params: dict | None, u):

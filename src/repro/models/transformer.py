@@ -410,12 +410,15 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
     idle slots stop advancing. Their logits rows are meaningless and must
     be masked by the caller (the engine samples only active rows).
 
-    Linear attention's (S, z) state rides the layer scan's carry as the
-    whole layer stack, not its scanned ``xs``/``ys``: each layer updates
-    its own slice in place (``attention.decode_step(layer=...)``), so the
-    pool's state is never sliced out, written back or copied per tick.
-    KV rings and SSM carries, one layer's at a time, stay scanned; a
-    mixed stack carries both in place (:func:`_mixed_decode_step`).
+    Linear attention's (S, z) state and the K and V rings ride the layer
+    scan's carry as whole layer stacks, not its scanned ``xs``/``ys``:
+    each layer updates its own entry in place
+    (``attention.decode_step(layer=...)``; a ring takes one row per live
+    slot), so the pool's state is never sliced out, written back or
+    copied per tick. SSM carries, and the rings of a paged pool or of a
+    local/global stack (whose ``lax.cond`` would copy a passed-through
+    stack whole), stay scanned one layer's at a time; a mixed stack
+    carries every kind in place (:func:`_mixed_decode_step`).
     """
     if cfg.layer_types:
         return _mixed_decode_step(params, cfg, cache, tokens, active)
@@ -424,7 +427,14 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
     act = None if active is None else active.astype(bool)
     slay_params = params.get("slay")
     kinds = jnp.asarray(_layer_kinds(cfg))
-    stacked = cache.attn is not None and cache.attn.s is not None
+    local_global = bool(cfg.local_global_period and cfg.local_window)
+    # The attention-cache leaves that ride the carry as whole stacks.
+    carried = ()
+    if cache.attn is not None and cache.attn.s is not None:
+        carried += ("s", "z")
+    if (cache.attn is not None and cache.attn.k is not None
+            and cache.pages is None and not local_global):
+        carried += ("k", "v")
 
     def body(carry, scanned):
         x, state = carry
@@ -454,9 +464,9 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
         k = rope(k[:, None], p1, cfg.rope_theta)[:, 0]
         spec_g = cfg.attention_spec(local=False)
         ac = scanned["attn"]
-        if stacked:
-            ac = ac._replace(s=state[0], z=state[1])
-        if cfg.local_global_period and cfg.local_window:
+        if carried:
+            ac = ac._replace(**dict(zip(carried, state)))
+        if local_global:
             spec_l = cfg.attention_spec(local=True)
 
             def _local():
@@ -487,9 +497,9 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
             y, nac = attn.decode_step(spec_g, slay_params, q, k, v, ac,
                                       active=act, layer=layer)
         a = jnp.einsum("bhk,hkd->bd", y, lp["attn"]["wo"])
-        if stacked:
-            state = (nac.s, nac.z)
-            nac = nac._replace(s=None, z=None)
+        if carried:
+            state = tuple(getattr(nac, n) for n in carried)
+            nac = nac._replace(**dict.fromkeys(carried))
         new["attn"] = nac
         if cfg.family == "hybrid":
             m, st = ssm.ssd_decode_step(
@@ -514,15 +524,15 @@ def decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
     state = None
     if cache.attn is not None:
         scanned["attn"] = cache.attn
-    if stacked:
-        state = (cache.attn.s, cache.attn.z)
-        scanned["attn"] = cache.attn._replace(s=None, z=None)
+    if carried:
+        state = tuple(getattr(cache.attn, n) for n in carried)
+        scanned["attn"] = cache.attn._replace(**dict.fromkeys(carried))
         scanned["layer"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     if cache.ssm is not None:
         scanned["ssm"] = cache.ssm
     (x, state), new = jax.lax.scan(body, (x, state), scanned)
-    if stacked:
-        new["attn"] = new["attn"]._replace(s=state[0], z=state[1])
+    if carried:
+        new["attn"] = new["attn"]._replace(**dict(zip(carried, state)))
     x = rmsnorm(params["final_norm"], x)
     table = params.get("unembed", params["embed"])
     logits = unembed(table, x, cfg.final_logit_softcap)
@@ -1279,8 +1289,10 @@ def _mixed_decode_step(params: dict, cfg: ArchConfig, cache: DecodeCache,
         p = _at(params["attn"], j)
         q, k, v = _qkv(cfg, p, h[:, None], pos[:, None])
         y, new = attn.decode_step(spec, None, q[:, 0], k[:, 0], v[:, 0],
-                                  _at(ac, j), active=act)
-        return jnp.einsum("bhk,hkd->bd", y, p["wo"]), _put(ac, new, j)
+                                  ac._replace(pos=_at(ac.pos, j)),
+                                  active=act, layer=j)
+        return (jnp.einsum("bhk,hkd->bd", y, p["wo"]),
+                new._replace(pos=_put(ac.pos, new.pos, j)))
 
     x, st, ac = _mixed_layers(params, cfg, x, cache, mamba, attention)
     step = 1 if act is None else act.astype(jnp.int32)

@@ -259,9 +259,25 @@ def check_kernel():
           f"no collectives ({nops} ops)")
 
 
+def check_ring():
+    """The KV-ring decode writes one row per live slot in place, per shard
+    on a 2-shard slot pool: rings, every other cache leaf and logits equal
+    the one-device one-hot reference bit for bit, as slot positions wrap
+    past max_len under random active masks, and the sharded decode step
+    has no collectives."""
+    from test_decode_hot_loop import RING_CFGS, ring_parity
+
+    mesh = make_serving_mesh(2)
+    for kind, make in sorted(RING_CFGS.items()):
+        text = ring_parity(make(), mesh)
+        nops = _assert_collective_free(text, f"ring[{kind}]")
+        print(f"ring OK kind={kind}: 2 shards match the one-hot reference, "
+              f"no collectives ({nops} ops)")
+
+
 CHECKS = {"parity": check_parity, "evict_reuse": check_evict_reuse,
           "fallback": check_fallback, "collectives": check_collectives,
-          "paged": check_paged, "kernel": check_kernel}
+          "paged": check_paged, "kernel": check_kernel, "ring": check_ring}
 
 
 def main():

@@ -268,6 +268,95 @@ def test_decode_step_carry_matches_xs_ys_oracle(arch, use_pallas):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _one_hot_rows(stack, rows, layer, at, live):
+    """Reference ring write: the select over every row of the layer's ring
+    that ``attention._write_rows`` replaced. Row ``at[b]`` of slot b takes
+    ``rows[b]`` where ``live[b]`` holds; the layer's whole ring is read
+    and written back."""
+    ring = stack[layer]                                   # (B, S, Hkv, d)
+    write = jnp.arange(ring.shape[1])[None, :] == at[:, None]
+    if live is not None:
+        write = write & live[:, None]
+    new = jnp.where(write[:, :, None, None],
+                    rows.astype(ring.dtype)[:, None], ring)
+    return stack.at[layer].set(new)
+
+
+RING_CFGS = {
+    "softmax": lambda: configs.get_smoke_config("slayformer-124m",
+                                                attn_kind="softmax"),
+    "yat": lambda: configs.get_smoke_config("slayformer-124m",
+                                            attn_kind="yat"),
+    "mixed": lambda: configs.ArchConfig(
+        name="ring-mixed", family="decoder", num_layers=3, d_model=32,
+        num_heads=4, num_kv_heads=2, head_dim=8, d_ff=64, vocab_size=64,
+        attn_kind="softmax", layer_types="attention,mamba,attention",
+        ssm_state=8, ssm_head_dim=8, chunk_size=8),
+}
+
+
+def ring_parity(cfg, mesh, *, slots=4, max_len=16, ticks=40, seed=0):
+    """Decode ``ticks`` ticks with random active masks over a pool of
+    ``slots`` rings of ``max_len`` rows, sharded over ``mesh``'s slot axis,
+    from prompts that leave every slot's position to wrap past
+    ``max_len``. The in-place row write must give the rings, every other
+    cache leaf and the logits of the one-hot reference (one device, the
+    layer scan through ``xs``/``ys`` for a homogeneous stack) bit for
+    bit. Returns the sharded decode step's compiled text."""
+    from repro.distributed import sharding as shd
+
+    params = api.init_params(cfg, jax.random.PRNGKey(seed))
+    rng = np.random.default_rng(seed)
+    pool = api.init_cache(cfg, slots, max_len)
+    for slot in range(slots):
+        n = int(rng.integers(1, max_len - 2))
+        toks = jnp.asarray(rng.integers(3, cfg.vocab_size, (1, n)), jnp.int32)
+        _, req = api.prefill(params, cfg, {"tokens": toks}, max_len=max_len)
+        pool = api.write_slot(cfg, pool, req, slot)
+    axes, _ = shd.pool_slot_axes(mesh, shd.DEFAULT_RULES, slots)
+    c_sh = shd.serving_cache_sharding(mesh, shd.DEFAULT_RULES, pool,
+                                      num_slots=slots)
+    v_sh = shd.serving_vector_sharding(mesh, num_slots=slots)
+
+    def step(cache, tok, act):
+        return api.decode_step(params, cfg, cache, tok[:, None], act)
+
+    new = jax.jit(shd.in_slot_pool(step, mesh, axes),
+                  in_shardings=(c_sh, v_sh, v_sh))
+    mixed = bool(cfg.layer_types)
+
+    def old(cache, tok, act):
+        with pytest.MonkeyPatch.context() as m:
+            m.setattr(attn, "_write_rows", _one_hot_rows)
+            if mixed:
+                return jax.jit(step)(cache, tok, act)
+            return jax.jit(_decode_step_xs_ys, static_argnums=1)(
+                params, cfg, cache, tok[:, None], act)
+
+    with mesh:
+        got = jax.device_put(pool, c_sh)
+    want = pool
+    for _ in range(ticks):
+        tok = jnp.asarray(rng.integers(3, cfg.vocab_size, slots), jnp.int32)
+        act = jnp.asarray(rng.random(slots) < 0.7)
+        lg_got, got = new(got, tok, act)
+        lg_want, want = old(want, tok, act)
+        np.testing.assert_array_equal(np.asarray(lg_got), np.asarray(lg_want))
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert int(np.min(np.asarray(got.pos))) > max_len     # every ring wrapped
+    return new.lower(got, tok, act).compile().as_text()
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("kind", sorted(RING_CFGS))
+def test_ring_row_write_matches_one_hot_select(kind):
+    """One row per live slot, written in place, is the one-hot select over
+    the whole ring, bit for bit, on a one-device pool (the 2-shard pool:
+    ``tests/sharded_driver.py --check ring``)."""
+    ring_parity(RING_CFGS[kind](), make_host_mesh())
+
+
 # ---------------------------------------------------------------------------
 # K-tick macro-stepping
 # ---------------------------------------------------------------------------

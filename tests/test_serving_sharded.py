@@ -173,6 +173,14 @@ def test_sharded_decode_has_no_collectives():
 
 
 @pytest.mark.serving
+def test_sharded_ring_write_matches_one_hot_select():
+    """On a 2-shard slot pool the KV-ring decode writes one row per live
+    slot per shard, bit-identical to the one-device one-hot reference,
+    with no collectives."""
+    _run_driver("ring")
+
+
+@pytest.mark.serving
 def test_sharded_decode_kernel_runs_per_shard():
     """With the Pallas decode kernel on, a slot-sharded pool runs it once
     per shard (shard_map): streams match the one-device pool and the

@@ -12,8 +12,11 @@ imports every test file.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -100,19 +103,14 @@ def test_kernel_compiles_for_v5e(one_chip, kernel):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_macro_decode_moves_pool_state_only_in_kernel(one_chip, monkeypatch):
-    """The serving macro-step (K = 2 ticks, 8 slots, 2 layers) updates the
-    layer-stacked SLAY state in place: no copy, dynamic slice or dynamic
-    update, fused or not, produces the stack or one layer of it, in the
-    model's layout or the kernel's view. The decode kernel is the only op
-    that touches it."""
-    from repro.analysis import hlo
+def _macro_hlo(one_chip, monkeypatch, cfg, slots, max_len, num_ticks=2):
+    """Compiled text of the serving macro-step (``num_ticks`` ticks over a
+    pool of ``slots`` slots of ``max_len`` rows, cache donated) for the
+    described chip, and the pool's abstract cache."""
     from repro.kernels import ops
     from repro.models import api
     from repro.serving import engine
 
-    nl, slots, hkv = 2, 8, CFG.num_kv_heads
-    cfg = get_config("slayformer-124m", num_layers=nl)
     # Whole-program compile for the described chip: take the TPU branch.
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
 
@@ -120,19 +118,34 @@ def test_macro_decode_moves_pool_state_only_in_kernel(one_chip, monkeypatch):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one_chip), tree)
 
-    params = placed(jax.eval_shape(
-        lambda: api.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = placed(jax.eval_shape(lambda: api.init_cache(cfg, slots, 2048)))
+    params = placed(api.abstract_params(cfg))
+    cache = placed(jax.eval_shape(lambda: api.init_cache(cfg, slots,
+                                                         max_len)))
     vectors = [jax.ShapeDtypeStruct((slots,), dt, sharding=one_chip)
                for dt in (jnp.int32, jnp.bool_, jnp.int32, jnp.int32,
                           jnp.int32, jnp.int32)]
 
     def macro(params, cache, *vectors):
         return engine._macro_decode(params, cache, *vectors, cfg=cfg,
-                                    num_ticks=2, temperature=0.0, seed=0)
+                                    num_ticks=num_ticks, temperature=0.0,
+                                    seed=0)
 
     text = jax.jit(macro, donate_argnums=(1,)).lower(
         params, cache, *vectors).compile().as_text()
+    return text, cache
+
+
+def test_macro_decode_moves_pool_state_only_in_kernel(one_chip, monkeypatch):
+    """The serving macro-step (K = 2 ticks, 8 slots, 2 layers) updates the
+    layer-stacked SLAY state in place: no copy, dynamic slice or dynamic
+    update, fused or not, produces the stack or one layer of it, in the
+    model's layout or the kernel's view. The decode kernel is the only op
+    that touches it."""
+    from repro.analysis import hlo
+
+    nl, slots, hkv = 2, 8, CFG.num_kv_heads
+    cfg = get_config("slayformer-124m", num_layers=nl)
+    text, _ = _macro_hlo(one_chip, monkeypatch, cfg, slots, 2048)
     assert "tpu_custom_call" in text
     rows = slots * hkv
     state = {f"f32[{','.join(map(str, s))}]" for s in (
@@ -155,41 +168,21 @@ def test_mixed_macro_decode_updates_state_stacks_in_place(one_chip,
     place. No copy produces either stack, nor one layer of it: each
     layer's body reads and writes its own entry of its kind's stack,
     riding the loop carry. (Under ``lax.cond`` between the two bodies,
-    the branch that passes a stack through copies it whole.)"""
+    the branch that passes a stack through copies it whole.) No select
+    produces a ring stack, a layer of it or a ring: the new K/V row is
+    written for each live slot alone."""
     import json
 
     from bench import serving, spec
     from repro.analysis import hlo
-    from repro.kernels import ops
-    from repro.models import api
-    from repro.serving import engine
 
-    slots, max_len = 64, 2048
     with open(spec.config_path("granite-4.0-h-micro",
                                spec.load_benchmark())) as f:
         cfg = json.load(f)
     cfg["arch"].update(num_layers=6, layer_types="mamba,mamba,attention,"
                        "mamba,attention,mamba")
     cfg = serving.arch_config(cfg)
-    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-
-    def placed(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-
-    params = placed(api.abstract_params(cfg))
-    cache = placed(jax.eval_shape(lambda: api.init_cache(cfg, slots,
-                                                         max_len)))
-    vectors = [jax.ShapeDtypeStruct((slots,), dt, sharding=one_chip)
-               for dt in (jnp.int32, jnp.bool_, jnp.int32, jnp.int32,
-                          jnp.int32, jnp.int32)]
-
-    def macro(params, cache, *vectors):
-        return engine._macro_decode(params, cache, *vectors, cfg=cfg,
-                                    num_ticks=2, temperature=0.0, seed=0)
-
-    text = jax.jit(macro, donate_argnums=(1,)).lower(
-        params, cache, *vectors).compile().as_text()
+    text, cache = _macro_hlo(one_chip, monkeypatch, cfg, 64, 2048)
     names = {jnp.dtype(jnp.float32): "f32", jnp.dtype(jnp.bfloat16): "bf16"}
     stacks = set()
     for leaf in (cache.ssm.h, cache.attn.k, cache.attn.v):
@@ -201,3 +194,99 @@ def test_mixed_macro_decode_updates_state_stacks_in_place(one_chip,
               and (hlo.base_opcode(i.opcode) == "copy"
                    or (i.opcode == "fusion" and "copy" in i.name))]
     assert not copies, "\n".join(copies)
+    # Nor does a select over a whole ring write the new K/V row: each
+    # live slot writes its one row.
+    rings = set()
+    for leaf in (cache.attn.k, cache.attn.v):
+        s = leaf.shape
+        for shape in (s, (1, *s[1:]), s[1:]):
+            rings.add(f"bf16[{','.join(map(str, shape))}]")
+    ops, _, _ = _ops(text)
+    root_of = {o["comp"]: o for o in ops if o["root"]}
+    selects = [o["name"] for o in ops if o["shape"] in rings
+               and ("select" in o["name"] or o["opcode"] == "select"
+                    or (o["opcode"] == "fusion"
+                        and root_of[o["calls"][0]]["opcode"] == "select"))]
+    assert not selects, selects
+
+
+_HEADER = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_OP = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*(\S+?\[[^\]]*\])\S*\s+"
+                 r"([\w\-]+)\(([^)]*)\)")
+
+
+def _ops(text):
+    """The array-valued instructions of compiled HLO text, each with its
+    computation: (name, shape, opcode, operand names, root flag, called
+    computations), and the set of fused computations. Shapes drop their
+    layout: ``bf16[2,32,2048,12,64]``."""
+    ops, comp, entry = [], None, None
+    for line in text.splitlines():
+        if comp is None:
+            m = _HEADER.match(line)
+            if m and not line[0].isspace():
+                comp = m.group(2)
+                entry = comp if m.group(1) else entry
+            continue
+        if line.strip() == "}":
+            comp = None
+            continue
+        m = _OP.match(line)
+        if m:
+            ops.append(dict(
+                comp=comp, root=bool(m.group(1)), name=m.group(2),
+                shape=m.group(3), opcode=m.group(4),
+                args=re.findall(r"%([\w.\-]+)", m.group(5)),
+                calls=re.findall(r"calls=%([\w.\-]+)", line)))
+    fused = {c for o in ops if o["opcode"] == "fusion" for c in o["calls"]}
+    return ops, fused, entry
+
+
+@pytest.mark.parametrize("num_ticks", [2, 4])
+def test_macro_decode_writes_one_ring_row_per_slot(one_chip, monkeypatch,
+                                                   num_ticks):
+    """The softmax macro-step at slayformer-124m-softmax widths (2 layers,
+    32 slots of 2048-row rings) carries the K and V ring stacks through
+    the layer scan and writes one row per slot in place. Inside the tick
+    and layer loops no copy, select or dynamic slice produces a stack
+    (nl, B, S, Hkv, dh), one layer of it or one ring (fused or not: a
+    dynamic slice may only feed the attention inside its fusion), and
+    the writes into each stack are one (Hkv, dh) row per slot. At the
+    program's edge at most one relayout copy per stack each way."""
+    nl = 2
+    cfg = get_config("slayformer-124m", attn_kind="softmax", num_layers=nl)
+    text, cache = _macro_hlo(one_chip, monkeypatch, cfg, 32, 2048,
+                             num_ticks)
+    ring = cache.attn.k.shape[1:]
+    ops, fused, entry = _ops(text)
+    stack = "bf16[{}]".format(",".join(map(str, (nl, *ring))))
+    shapes = {"bf16[{}]".format(",".join(map(str, s)))
+              for s in ((nl, *ring), (1, *ring), ring)}
+    by_name = {o["name"]: o for o in ops}
+    root_of = {o["comp"]: o for o in ops if o["root"]}
+    moves = ("copy", "select", "dynamic-slice")
+
+    def made_by(o):
+        if o["opcode"] == "fusion":
+            return root_of[o["calls"][0]]["opcode"]
+        return o["opcode"]
+
+    bad = [o["name"] for o in ops
+           if o["comp"] not in fused and o["comp"] != entry
+           and o["shape"] in shapes and made_by(o) in moves]
+    assert not bad, bad
+    row = int(np.prod(ring[2:]))        # one slot's (Hkv, dh) row
+    writes = [o for o in ops if o["shape"] == stack
+              and o["opcode"] in ("scatter", "dynamic-update-slice")]
+    sizes = []
+    for o in writes:
+        upd = by_name[o["args"][2 if o["opcode"] == "scatter" else 1]]
+        dims = upd["shape"].split("[")[1].rstrip("]").split(",")
+        sizes.append(int(np.prod([int(d) for d in dims])))
+    # K and V: a dynamic update of one row for each slot, or a scatter of
+    # one row per slot.
+    assert sorted(sizes) in ([row] * 2 * ring[0],
+                             [row * ring[0]] * 2), sizes
+    edge = [o["name"] for o in ops if o["comp"] == entry
+            and o["shape"] == stack and made_by(o) == "copy"]
+    assert len(edge) <= 4, edge
