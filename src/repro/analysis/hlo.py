@@ -19,12 +19,6 @@ declarative contracts against it:
 The parser is deliberately tolerant: lines that are not instructions
 (computation headers, braces, comments, metadata continuation) are
 skipped, so it works across XLA text-format drift.
-
-Sibling: :mod:`repro.launch.hlo_cost` parses the same text for a
-*quantitative* cost model (FLOPs / HBM bytes / collective wire bytes);
-this module is the *qualitative* contract surface — which opcodes exist
-at all, and what aliases what. They stay separate because the cost model
-needs loop-trip/shape arithmetic the contract checks never touch.
 """
 from __future__ import annotations
 

@@ -92,7 +92,7 @@ class Options:
 
     # CLK001 applies under these repo-relative path prefixes only: the
     # serving stack must route every wall read through the injectable
-    # clock; benchmarks/launch legitimately measure wall time.
+    # clock; benchmarks legitimately measure wall time.
     clock_paths: tuple = ("src/repro/serving/",)
     # Paths skipped entirely (known-bad lint fixtures, caches).
     exclude_parts: tuple = ("__pycache__", "tests/fixtures/")

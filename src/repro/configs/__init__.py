@@ -1,6 +1,6 @@
 """Config registry: the 10 assigned architectures + the paper's SLAYformer.
 
-    cfg = configs.get_config("qwen3-32b")          # full (dry-run only)
+    cfg = configs.get_config("qwen3-32b")          # published widths
     cfg = configs.get_smoke_config("qwen3-32b")    # reduced (CPU smoke test)
 
 Every arch runs with the paper's SLAY attention by default
@@ -12,8 +12,7 @@ from __future__ import annotations
 import dataclasses
 import importlib
 
-from repro.configs.base import (ArchConfig, ShapeCell, SHAPE_CELLS, get_cell,
-                                input_specs)
+from repro.configs.base import ArchConfig
 
 _MODULES = {
     "phi4-mini-3.8b": "phi4_mini",
@@ -50,6 +49,6 @@ def get_smoke_config(name: str, **overrides) -> ArchConfig:
 
 
 __all__ = [
-    "ArchConfig", "ShapeCell", "SHAPE_CELLS", "ASSIGNED_ARCHS", "ALL_ARCHS",
-    "get_cell", "get_config", "get_smoke_config", "input_specs",
+    "ArchConfig", "ASSIGNED_ARCHS", "ALL_ARCHS", "get_config",
+    "get_smoke_config",
 ]

@@ -1,23 +1,14 @@
-"""Architecture + shape-cell configuration system.
+"""Architecture and serving configuration.
 
 Every assigned architecture is an :class:`ArchConfig` (one module per arch in
-this package, exposing ``CONFIG`` and ``smoke_config()``). Shape cells follow
-the assignment:
-
-    train_4k     seq 4096,   batch 256   -> train_step
-    prefill_32k  seq 32768,  batch 32    -> prefill (full forward, no loss)
-    decode_32k   seq 32768,  batch 128   -> serve_step (1 token, 32k cache)
-    long_500k    seq 524288, batch 1     -> serve_step (sub-quadratic only)
-
-``input_specs`` returns jax.ShapeDtypeStruct stand-ins — no allocation — for
-the dry-run's .lower().
+this package, exposing ``CONFIG`` and ``smoke_config()``); the continuous
+serving engine takes a :class:`ServingConfig`.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.features import SlayFeatureConfig
@@ -355,65 +346,3 @@ class ServingConfig:
                 "speculative decoding and the prefix cache are mutually "
                 "exclusive (a prefix-seeded verifier slot has no draft-side "
                 "snapshot to seed from)")
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeCell:
-    name: str
-    seq_len: int
-    global_batch: int
-    mode: str                       # train | prefill | decode
-
-
-SHAPE_CELLS = (
-    ShapeCell("train_4k", 4_096, 256, "train"),
-    ShapeCell("prefill_32k", 32_768, 32, "prefill"),
-    ShapeCell("decode_32k", 32_768, 128, "decode"),
-    ShapeCell("long_500k", 524_288, 1, "decode"),
-)
-
-
-def get_cell(name: str) -> ShapeCell:
-    for c in SHAPE_CELLS:
-        if c.name == name:
-            return c
-    raise KeyError(name)
-
-
-def input_specs(cfg: ArchConfig, cell: ShapeCell) -> dict:
-    """ShapeDtypeStruct stand-ins for every model input of this cell."""
-    B, L = cell.global_batch, cell.seq_len
-    i32 = jnp.int32
-    act = cfg.activation_dtype
-
-    def tok(shape):
-        return jax.ShapeDtypeStruct(shape, i32)
-
-    if cell.mode == "train":
-        specs = {}
-        lt = L
-        if cfg.frontend == "vision":
-            specs["patch_embeds"] = jax.ShapeDtypeStruct(
-                (B, cfg.num_patches, cfg.d_model), act)
-            lt = L - cfg.num_patches
-        if cfg.frontend == "audio":
-            specs["frame_embeds"] = jax.ShapeDtypeStruct(
-                (B, cfg.enc_seq, cfg.d_model), act)
-        specs["tokens"] = tok((B, lt))
-        specs["labels"] = tok((B, lt))
-        return specs
-    if cell.mode == "prefill":
-        specs = {}
-        lt = L
-        if cfg.frontend == "vision":
-            specs["patch_embeds"] = jax.ShapeDtypeStruct(
-                (B, cfg.num_patches, cfg.d_model), act)
-            lt = L - cfg.num_patches
-        if cfg.frontend == "audio":
-            specs["frame_embeds"] = jax.ShapeDtypeStruct(
-                (B, cfg.enc_seq, cfg.d_model), act)
-        specs["tokens"] = tok((B, lt))
-        return specs
-    # decode: one new token; the cache (sized for seq_len) is a separate
-    # donated argument produced by serving.init_cache_specs.
-    return {"tokens": tok((B, 1))}

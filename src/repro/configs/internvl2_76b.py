@@ -1,5 +1,5 @@
 """internvl2-76b — VLM: InternLM2-style LM backbone; InternViT frontend is a
-STUB (``input_specs`` provides precomputed patch embeddings)
+STUB (the caller provides precomputed patch embeddings)
 [arXiv:2404.16821; unverified]."""
 import dataclasses
 

@@ -1,5 +1,5 @@
 """whisper-small — enc-dec audio backbone; conv frontend is a STUB
-(``input_specs`` provides precomputed frame embeddings)
+(the caller provides precomputed frame embeddings)
 [arXiv:2212.04356; unverified]."""
 import dataclasses
 

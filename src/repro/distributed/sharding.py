@@ -17,7 +17,7 @@ Model code annotates parameters with *logical* axis names (see
 The fallback rule: if a tensor dim is not divisible by the mesh-axis size
 (e.g. granite's single KV head over 16-way model parallelism), the rule
 engine *drops the mesh axis* (replicates) rather than failing — recorded so
-the dry-run report can show which dims replicated.
+callers can report which dims replicated.
 
 Rules are data (a dataclass), so perf iterations can swap whole schemes
 (§Perf beyond-paper experiments) without touching model code.

@@ -1,1 +1,1 @@
-"""Launchers: production mesh factory, multi-pod dry-run, training driver."""
+"""Launchers: mesh factories and the persistent compilation cache."""

@@ -1,8 +1,9 @@
 """Production mesh factory.
 
 Kept as FUNCTIONS (not module-level constants) so importing this module
-never touches jax device state — the dry-run must set
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first init.
+never touches jax device state — a process that forces a host device count
+(``XLA_FLAGS=--xla_force_host_platform_device_count=N``) must set it before
+first init.
 
 Every mesh in the repo comes from :func:`make_mesh`, which gives each axis
 ``AxisType.Auto``: the model code places arrays with ``with_sharding_constraint``

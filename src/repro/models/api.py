@@ -25,7 +25,7 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> dict:
 
 
 def abstract_params(cfg: ArchConfig) -> dict:
-    """ShapeDtypeStruct pytree of the parameters — no allocation (dry-run)."""
+    """ShapeDtypeStruct pytree of the parameters — no allocation."""
     return jax.eval_shape(
         lambda k: init_params(cfg, k), jax.ShapeDtypeStruct((2,), "uint32"))
 
@@ -62,7 +62,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, *,
 def abstract_cache(cfg: ArchConfig, batch: int, max_len: int, *,
                    page_size: int = 0, num_pages: int = 0,
                    shards: int = 1):
-    """Cache shapes without allocation (decode dry-run cells)."""
+    """Cache shapes without allocation."""
     return jax.eval_shape(lambda: init_cache(cfg, batch, max_len,
                                              page_size=page_size,
                                              num_pages=num_pages,

@@ -1,6 +1,6 @@
 """Whisper-style encoder-decoder backbone (audio frontend is a stub).
 
-Per the assignment, the conv-mel frontend is NOT modeled: ``input_specs``
+Per the assignment, the conv-mel frontend is NOT modeled: the caller
 provides precomputed frame embeddings (B, enc_seq, d_model). The backbone is
 faithful: bidirectional encoder self-attention, causal decoder
 self-attention, and decoder->encoder cross-attention. Under the SLAY backend

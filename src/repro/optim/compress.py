@@ -13,7 +13,7 @@ Usage (in the train loop, between grad computation and the optimizer):
 
 Under GSPMD the all-reduce itself is inserted by XLA; compressing the
 tensors that feed it shrinks the collective payload 4x (bf16) / 2x (int8 vs
-bf16). The dry-run's collective-bytes report (§Roofline) quantifies this.
+bf16).
 """
 from __future__ import annotations
 

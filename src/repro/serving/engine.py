@@ -484,8 +484,8 @@ class ServingMetrics:
                        if s.ttft_ticks is not None)
         ttfts_s = sorted(s.ttft_s for s in self.per_request.values()
                          if s.ttft_s is not None)
-        # Split TTFT by prefix-cache seeding — the §11 win the bench
-        # contract asserts on (cached admissions skip prefill work).
+        # Split TTFT by prefix-cache seeding — the §11 win the prefix-cache
+        # tests assert on (cached admissions skip prefill work).
         ttfts_c = sorted(s.ttft_ticks for s in self.per_request.values()
                          if s.ttft_ticks is not None and s.prefix_cached)
         ttfts_w = sorted(s.ttft_ticks for s in self.per_request.values()
@@ -1907,9 +1907,10 @@ class ContinuousServingEngine:
 
     def jit_cache_entries(self) -> dict:
         """Live jit-cache entry counts per engine entry point — the
-        recompile budget CI asserts on (the decode hot loop must stay at
-        exactly one entry; prefill entries are bounded by the chunk/bucket
-        counts, never by the number of distinct prompt lengths).
+        recompile budget the hot-loop tests assert on (the decode hot loop
+        must stay at exactly one entry; prefill entries are bounded by the
+        chunk/bucket counts, never by the number of distinct prompt
+        lengths).
 
         Counting relies on jax's ``_cache_size`` introspection; entry
         points it cannot measure are omitted (callers treat a missing key

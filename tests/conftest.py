@@ -1,6 +1,6 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — unit/smoke tests run on the
-single real CPU device; only the dry-run sets the 512-device placeholder
-flag (and only in its own process)."""
+single real CPU device; multi-device checks force a host device count in a
+subprocess of their own (tests/sharded_driver.py)."""
 import os
 
 import jax

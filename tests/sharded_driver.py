@@ -17,24 +17,31 @@ fallback, and the zero-collective decode hot loop.
 from __future__ import annotations
 
 import argparse
-import os
-import sys
 
 import jax
 import numpy as np
 
-# The bench package lives at the repo root (not on PYTHONPATH=src);
-# reuse its seeded trace generator rather than keeping a hand-synced copy.
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
-    __file__))))
+from repro import configs
+from repro.analysis import hlo as hlo_lib
+from repro.configs.base import ServingConfig
+from repro.launch.mesh import make_serving_mesh
+from repro.models import api
+from repro.serving.engine import ContinuousServingEngine, Request
 
-from benchmarks.serving_bench import _poisson_trace as _bench_trace  # noqa: E402,E501
-from repro import configs  # noqa: E402
-from repro.analysis import hlo as hlo_lib  # noqa: E402
-from repro.configs.base import ServingConfig  # noqa: E402
-from repro.launch.mesh import make_serving_mesh  # noqa: E402
-from repro.models import api  # noqa: E402
-from repro.serving.engine import ContinuousServingEngine  # noqa: E402
+
+def poisson_trace(rng, n: int, rate: float, prompt_range, vocab: int,
+                  max_new: int) -> list[Request]:
+    """n requests with exp(rate) inter-arrival ticks and random prompts."""
+    t = 0.0
+    reqs = []
+    lo, hi = prompt_range
+    for _ in range(n):
+        t += rng.exponential(1.0 / rate)
+        plen = int(rng.integers(lo, hi + 1))
+        prompt = rng.integers(3, vocab, size=plen).astype(np.int32)
+        reqs.append(Request(prompt, max_new_tokens=max_new,
+                            arrival_time=t))
+    return reqs
 
 
 def _assert_collective_free(hlo_text: str, label: str) -> int:
@@ -57,9 +64,9 @@ def _setup(attn_kind="slay"):
 
 def _poisson_trace(cfg, n=6, rate=0.5, prompt_range=(3, 12), max_new=6,
                    seed=1234):
-    """Mixed-length Poisson arrivals — serving_bench's generator."""
-    return _bench_trace(np.random.default_rng(seed), n, rate, prompt_range,
-                        cfg.vocab_size, max_new)
+    """Mixed-length Poisson arrivals for the checks below."""
+    return poisson_trace(np.random.default_rng(seed), n, rate, prompt_range,
+                         cfg.vocab_size, max_new)
 
 
 def _run(cfg, params, *, data, num_slots, macro_ticks, temperature=0.0,

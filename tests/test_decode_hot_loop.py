@@ -7,6 +7,8 @@ Pallas active-row oracle, both cache regimes), K-tick macro-stepping
 (K=1 vs K>1 token-stream and eviction parity), the length-bucketed masked
 prefill fallback, and the host-sync cadence metrics.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +23,7 @@ from repro.models import attention as attn
 from repro.serving import sampling
 from repro.serving.engine import (ContinuousServingEngine, Request,
                                   ServingEngine)
+from sharded_driver import poisson_trace
 
 
 @pytest.fixture(scope="module")
@@ -544,24 +547,67 @@ def test_masked_prefill_unsupported_families_raise():
 # ---------------------------------------------------------------------------
 
 
+# Every decode regime the engine serves, as (arch, config overrides,
+# ServingConfig overrides, trace seed). Four Poisson requests (prompts of
+# 3-8 tokens, 16 new tokens each) on a 2-slot pool at K=8; the exact-yat
+# pair replays the speculative contract trace (seed 10, tie-free argmaxes).
+_YAT = {"attn_kind": "yat_spherical", "slay_anchors": 16, "slay_prf": 32}
+_CADENCE_REGIMES = {
+    "constant_state": ("slayformer-124m", {"attn_kind": "slay"}, {}, 1234),
+    "kv_ring": ("slayformer-124m", {"attn_kind": "softmax"}, {}, 1234),
+    "kv_ring_paged": ("slayformer-124m", {"attn_kind": "softmax"},
+                      {"page_size": 8}, 1234),
+    "exact_yat": ("slayformer-124m", _YAT, {}, 10),
+    "ssm_scan": ("mamba2-780m", {}, {}, 1234),
+    "hybrid_scan": ("hymba-1.5b", {}, {}, 1234),
+    "speculative": ("slayformer-124m", _YAT,
+                    {"speculative": True, "spec_gamma": 2}, 10),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _model(arch, overrides):
+    cfg = configs.get_smoke_config(arch, **dict(overrides))
+    return cfg, api.init_params(cfg, jax.random.PRNGKey(0))
+
+
 @pytest.mark.serving
-def test_host_sync_cadence_contract(setup):
+@pytest.mark.parametrize("regime,load", [
+    ("constant_state", 0.25), ("constant_state", 1.0), ("kv_ring", 0.25),
+    ("kv_ring", 1.0), ("kv_ring_paged", 1.0), ("exact_yat", 1.0),
+    ("ssm_scan", 1.0), ("hybrid_scan", 1.0), ("speculative", 1.0),
+], ids=lambda v: v if isinstance(v, str) else f"load{v:g}")
+def test_host_sync_cadence_contract(setup, regime, load):
     """With K=8 and enough decode work, the decode loop syncs to host at
     most once per 8 generated tokens, dispatches once per pool (never per
-    slot), and the macro-step stays a single jit cache entry."""
-    cfg, params, mesh = setup
-    prompts = _prompts(cfg, (5, 7, 4, 6), seed=6)
-    reqs = [Request(p, max_new_tokens=16, arrival_time=float(i))
-            for i, p in enumerate(prompts)]
+    slot), and the hot loop (the macro-step, or the speculative macro-step)
+    stays a single jit cache entry within a budget of 24 live entries, in
+    every decode regime; the pool drains to zero occupancy and queue
+    depth. The scan-carry families prefill chunk by chunk, never through
+    the bucketed fallback."""
+    mesh = setup[2]
+    arch, overrides, serving_kw, seed = _CADENCE_REGIMES[regime]
+    cfg, params = _model(arch, tuple(sorted(overrides.items())))
+    if regime in ("ssm_scan", "hybrid_scan"):
+        assert api.supports_chunked_prefill(cfg), arch
+    reqs = poisson_trace(np.random.default_rng(seed), 4, load, (3, 8),
+                         cfg.vocab_size, 16)
     eng = ContinuousServingEngine(
         cfg, params, mesh,
-        serving=ServingConfig(num_slots=2, max_len=64, prefill_chunk=4,
-                              macro_ticks=8))
+        serving=ServingConfig(num_slots=2, max_len=32, prefill_chunk=4,
+                              macro_ticks=8, **serving_kw))
     _, summary = eng.run(reqs)
     assert summary["requests_completed"] == 4
     assert summary["host_syncs_per_token"] <= 1.0 / 8 + 1e-9
     assert summary["tokens_per_dispatch"] >= 8.0
     assert summary["dispatches_per_decode_tick"] <= 1.0
+    assert summary["final_occupancy"] == 0
+    assert summary["final_queue_depth"] == 0
     entries = eng.jit_cache_entries()
-    assert entries["macro_decode"] == 1
+    hot = "spec_macro" if regime == "speculative" else "macro_decode"
+    assert entries[hot] == 1, entries
     assert entries["sample"] == 1
+    assert sum(v for v in entries.values() if v > 0) <= 24, entries
+    if regime in ("ssm_scan", "hybrid_scan"):
+        assert summary["bucket_misses"] == 0 == summary["bucket_hits"]
+        assert summary["prefill_ticks"] > 0

@@ -15,6 +15,7 @@ from repro.models import api
 from repro.serving import pages
 from repro.serving.engine import ContinuousServingEngine, Request
 from repro.serving.faults import FaultInjector
+from sharded_driver import poisson_trace
 
 
 # ---------------------------------------------------------------------------
@@ -236,8 +237,9 @@ def _trace(cfg, n, seed, max_new=8):
 def _run(cfg, params, mesh, reqs, *, page_size=0, injector=None, **kw):
     eng = ContinuousServingEngine(
         cfg, params, mesh, fault_injector=injector,
-        serving=ServingConfig(num_slots=2, max_len=64, prefill_chunk=4,
-                              macro_ticks=4, page_size=page_size, **kw))
+        serving=ServingConfig(**{"num_slots": 2, "max_len": 64,
+                                 "prefill_chunk": 4, "macro_ticks": 4,
+                                 "page_size": page_size, **kw}))
     outs, summary = eng.run([Request(r.prompt, max_new_tokens=r.max_new_tokens,
                                      arrival_time=r.arrival_time)
                              for r in reqs])
@@ -245,18 +247,26 @@ def _run(cfg, params, mesh, reqs, *, page_size=0, injector=None, **kw):
 
 
 @pytest.mark.serving
-def test_engine_paged_streams_byte_identical(paged_setup):
+@pytest.mark.parametrize("trace", ["mixed", "poisson"])
+def test_engine_paged_streams_byte_identical(paged_setup, trace):
     """Paged KV ring == unpaged: token streams byte-identical, page math
-    visible in the summary, zero pages leaked after drain."""
+    visible in the summary, zero pages leaked after drain — on a mixed
+    trace at K=4 and on four Poisson requests (16 new tokens each) at
+    K=8 in 32-row rings."""
     cfg, params, mesh = paged_setup
-    reqs = _trace(cfg, 5, seed=3)
-    _, o1, s1 = _run(cfg, params, mesh, reqs)
-    e2, o2, s2 = _run(cfg, params, mesh, reqs, page_size=8)
+    if trace == "mixed":
+        reqs, kw = _trace(cfg, 5, seed=3), {}
+    else:
+        reqs = poisson_trace(np.random.default_rng(1234), 4, 1.0, (3, 8),
+                             cfg.vocab_size, 16)
+        kw = {"max_len": 32, "macro_ticks": 8}
+    _, o1, s1 = _run(cfg, params, mesh, reqs, **kw)
+    e2, o2, s2 = _run(cfg, params, mesh, reqs, page_size=8, **kw)
     assert s2["requests_completed"] == len(reqs) == s1["requests_completed"]
     for rid in o1:
         np.testing.assert_array_equal(o1[rid], o2[rid])
     assert s1.get("num_pages", 0) == 0           # unpaged run: no pool
-    assert s2["num_pages"] == 2 * (64 // 8)
+    assert s2["num_pages"] == 2 * (kw.get("max_len", 64) // 8)
     assert s2["pages_peak"] >= 1
     assert s2["final_pages_in_use"] == 0
     e2.page_pool.check()

@@ -146,11 +146,27 @@ def _shared_prefix_reqs(cfg, n=4, prefix_len=8, seed=17):
             for i in range(n)]
 
 
-def _serve(cfg, params, mesh, reqs, *, pc=None, page_size=0):
+def _poisson_prefix_reqs(cfg, n=4, chunk=4, seed=99):
+    """n Poisson arrivals (one per tick on average), each one shared
+    2-chunk system prefix plus a unique suffix of 1..chunk tokens."""
+    rng = np.random.default_rng(seed)
+    sys_prompt = rng.integers(3, cfg.vocab_size, size=2 * chunk)
+    t, reqs = 0.0, []
+    for _ in range(n):
+        t += rng.exponential(1.0)
+        suffix = rng.integers(3, cfg.vocab_size,
+                              size=int(rng.integers(1, chunk + 1)))
+        reqs.append(Request(
+            np.concatenate([sys_prompt, suffix]).astype(np.int32),
+            max_new_tokens=16, arrival_time=t))
+    return reqs
+
+
+def _serve(cfg, params, mesh, reqs, *, pc=None, page_size=0, macro_ticks=4):
     eng = ContinuousServingEngine(
         cfg, params, mesh, prefix_cache=pc,
         serving=ServingConfig(num_slots=2, max_len=32, prefill_chunk=4,
-                              macro_ticks=4, page_size=page_size))
+                              macro_ticks=macro_ticks, page_size=page_size))
     outs, summary = eng.run(
         [Request(r.prompt, max_new_tokens=r.max_new_tokens,
                  arrival_time=r.arrival_time) for r in reqs])
@@ -158,29 +174,36 @@ def _serve(cfg, params, mesh, reqs, *, pc=None, page_size=0):
 
 
 @pytest.mark.serving
-@pytest.mark.parametrize("arch_kind,page_size", [
-    (("slayformer-124m", "slay"), 0),         # constant-state (no paging)
-    (("slayformer-124m", "softmax"), 0),      # KV ring, unpaged
-    (("slayformer-124m", "softmax"), 8),      # KV ring, paged
-], ids=["constant_state", "kv_ring", "kv_ring_paged"])
-def test_cached_streams_byte_identical_to_cold(arch_kind, page_size, mesh):
-    """A warmed shared cache full-hits every request of a replayed trace
-    and the streams are byte-identical to the cold run, in every cache
-    regime; with paging on, no pages leak."""
+@pytest.mark.parametrize("arch_kind,page_size,make_reqs,macro_ticks", [
+    # constant-state (no paging)
+    (("slayformer-124m", "slay"), 0, _shared_prefix_reqs, 4),
+    # KV ring, unpaged
+    (("slayformer-124m", "softmax"), 0, _shared_prefix_reqs, 4),
+    # KV ring, paged
+    (("slayformer-124m", "softmax"), 8, _shared_prefix_reqs, 4),
+    # KV ring, paged, Poisson arrivals at K=8
+    (("slayformer-124m", "softmax"), 8, _poisson_prefix_reqs, 8),
+], ids=["constant_state", "kv_ring", "kv_ring_paged", "kv_ring_paged_poisson"])
+def test_cached_streams_byte_identical_to_cold(arch_kind, page_size,
+                                               make_reqs, macro_ticks, mesh):
+    """A warmed shared cache full-hits every request of a replayed trace,
+    the streams are byte-identical to the cold run and the median TTFT
+    drops below the cold run's, in every cache regime; with paging on, no
+    pages leak."""
     arch, kind = arch_kind
     cfg = configs.get_smoke_config(arch, attn_kind=kind)
     params = api.init_params(cfg, jax.random.PRNGKey(0))
-    reqs = _shared_prefix_reqs(cfg)
-    _, cold, s_cold = _serve(cfg, params, mesh, reqs,
-                             page_size=page_size)  # no cache: truly cold
+    reqs = make_reqs(cfg)
+    kw = {"page_size": page_size, "macro_ticks": macro_ticks}
+    _, cold, s_cold = _serve(cfg, params, mesh, reqs, **kw)  # truly cold
     assert s_cold["prefix_hits"] == 0
     pc = PrefixCache(64 * 1024 * 1024)
-    _serve(cfg, params, mesh, reqs, pc=pc, page_size=page_size)  # warm-up
-    _, warm, s_warm = _serve(cfg, params, mesh, reqs, pc=pc,
-                             page_size=page_size)
+    _serve(cfg, params, mesh, reqs, pc=pc, **kw)             # warm-up
+    _, warm, s_warm = _serve(cfg, params, mesh, reqs, pc=pc, **kw)
     assert s_warm["prefix_hits"] == len(reqs)     # replay: all full hits
     assert s_warm["prefix_tokens_reused"] == sum(len(r.prompt)
                                                  for r in reqs)
+    assert s_warm["ttft_ticks_p50"] < s_cold["ttft_ticks_p50"]
     for rid in cold:
         np.testing.assert_array_equal(cold[rid], warm[rid])
     if page_size:

@@ -1,9 +1,7 @@
 """Serving engines: lockstep reference (greedy determinism, eos actual
 lengths, constant-state vs KV decode) and the continuous-batching engine
 (staggered admission, eos eviction + slot reuse, streamed parity, chunked
-prefill continuation, both cache regimes, serving bench JSON)."""
-import os
-
+prefill continuation, both cache regimes)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -356,60 +354,3 @@ def test_engine_metrics_shape(setup):
     assert 0.0 <= summary["mean_slot_occupancy"] <= 1.0
     assert summary["tokens_generated"] == 6
     assert summary["ttft_ticks_p50"] is not None
-
-
-@pytest.mark.serving
-def test_serving_bench_smoke_emits_json(tmp_path, monkeypatch):
-    """The serving bench writes BENCH_serving.json with throughput + TTFT
-    at >= 2 load levels (the CI artifact contract)."""
-    from benchmarks import serving_bench
-    out = tmp_path / "BENCH_serving.json"
-    monkeypatch.setattr(serving_bench, "_JSON_PATH", str(out))
-    serving_bench.run(smoke=True)
-    assert os.path.exists(out)
-    import json
-    payload = json.loads(out.read_text())
-    rows = payload["results"]
-    loads = {(r["regime"], r["load"]) for r in rows}
-    assert len({ld for _, ld in loads}) >= 2          # >= 2 load levels
-    assert {rg for rg, _ in loads} == {"constant_state", "kv_ring",
-                                       "ssm_scan", "hybrid_scan",
-                                       "constant_state_sharded",
-                                       "kv_ring_paged", "prefix_cold",
-                                       "prefix_cached", "exact_yat",
-                                       "spec_constant_state"}
-    # Scan-carry families serve via chunked prefill — fallback retired.
-    for r in rows:
-        if r["regime"] in ("ssm_scan", "hybrid_scan"):
-            assert r["bucket_misses"] == 0 == r["bucket_hits"], r
-    for r in rows:
-        assert "decode_tokens_per_s" in r and "ttft_ticks_p50" in r
-        assert "stream_digest" in r
-    # §8 byte-identity: the sharded row replays the single-shard trace.
-    sharded = next(r for r in rows
-                   if r["regime"] == "constant_state_sharded")
-    assert sharded["slot_shards"] > 1
-    base = next(r for r in rows if r["regime"] == "constant_state"
-                and r["load"] == sharded["load"])
-    assert sharded["stream_digest"] == base["stream_digest"]
-    # §11 byte-identity: the paged row replays the kv_ring trace, and the
-    # prefix-cached replay full-hits every request of the cold run.
-    paged = next(r for r in rows if r["regime"] == "kv_ring_paged")
-    ring = next(r for r in rows if r["regime"] == "kv_ring"
-                and r["load"] == paged["load"])
-    assert paged["stream_digest"] == ring["stream_digest"]
-    assert paged["pages_peak"] >= 1 and paged["final_pages_in_use"] == 0
-    cold = next(r for r in rows if r["regime"] == "prefix_cold")
-    warm = next(r for r in rows if r["regime"] == "prefix_cached")
-    assert warm["stream_digest"] == cold["stream_digest"]
-    assert cold["prefix_hit_rate"] == 0.0
-    assert warm["prefix_hit_rate"] == 1.0
-    assert warm["ttft_ticks_p50"] < cold["ttft_ticks_p50"]
-    # §13 byte-identity: the draft-verify row's accepted streams replay
-    # the exact-yat baseline on the pinned contract trace.
-    spec = next(r for r in rows if r["regime"] == "spec_constant_state")
-    exact = next(r for r in rows if r["regime"] == "exact_yat"
-                 and r["load"] == spec["load"])
-    assert spec["stream_digest"] == exact["stream_digest"]
-    assert spec["draft_acceptance_rate"] >= 0.5
-    assert spec["tokens_per_dispatch"] > exact["tokens_per_dispatch"]

@@ -4,6 +4,8 @@ every lifecycle stage (queued, mid-prefill, mid-macro-step), tick/wall
 deadlines with EOS-wins ordering, NaN slot quarantine + retry, and the
 deterministic chaos harness (parity of non-faulted streams, seeded
 injector reproducibility)."""
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -16,6 +18,7 @@ from repro.serving import faults
 from repro.serving.engine import (AdmissionError, ContinuousServingEngine,
                                   QueueFullError, Request,
                                   RequestTooLargeError, ServingMetrics)
+from sharded_driver import poisson_trace
 
 pytestmark = pytest.mark.serving
 
@@ -365,22 +368,45 @@ def _trace(cfg, n=3, max_new=8):
             for i in range(n)]
 
 
+def _poisson(cfg):
+    """Four Poisson requests (prompts of 3-8 tokens, 16 new tokens each)."""
+    return poisson_trace(np.random.default_rng(1234), 4, 1.0, (3, 8),
+                         cfg.vocab_size, 16)
+
+
+# Per trace: (trace, engine overrides, injector seeds and NaN cadence).
+_NAN_RUNS = {
+    "mixed": (_trace, {"temperature": 0.7}, {"seed": 7, "nan_every": 5}),
+    "poisson": (_poisson, {"max_len": 32, "macro_ticks": 8},
+                {"seed": 418, "nan_every": 6}),
+}
+
+
 @pytest.mark.chaos
-def test_nan_quarantine_retry_reproduces_stream(setup):
+@pytest.mark.parametrize("trace", sorted(_NAN_RUNS))
+def test_nan_quarantine_retry_reproduces_stream(setup, trace):
     """Inject NaNs into live slots; the macro-step fault lane detects
-    them, the host quarantines + retries, and every successfully-finished
-    stream is byte-identical to the fault-free run — retry-from-scratch
-    is transparent under (seed, rid, idx)-keyed sampling."""
+    them within 4 macro-steps, the host quarantines + retries, every
+    request terminates and the pool drains, and every successfully-
+    finished stream is byte-identical to the fault-free run — retry-from-
+    scratch is transparent under (seed, rid, idx)-keyed sampling. The
+    hot loop keeps one dispatch per decode tick and one compiled
+    macro-step through the faults."""
     cfg, params, mesh = setup
-    base, _ = _engine(cfg, params, mesh,
-                      temperature=0.7).run(_trace(cfg))
-    inj = faults.FaultInjector(seed=7, nan_every=5)
-    eng = _engine(cfg, params, mesh, temperature=0.7, injector=inj)
-    outs, s = eng.run(_trace(cfg))
+    make_trace, kw, inj_kw = _NAN_RUNS[trace]
+    base, _ = _engine(cfg, params, mesh, **kw).run(make_trace(cfg))
+    inj = faults.FaultInjector(**inj_kw)
+    eng = _engine(cfg, params, mesh, injector=inj, **kw)
+    outs, s = eng.run(make_trace(cfg))
     assert s["faults_detected"] >= 1
     assert s["fault_retries"] >= 1
     assert s["fault_retries_succeeded"] >= 1
-    assert s["final_occupancy"] == 0
+    assert s["requests_terminated"] == len(base)
+    assert s["final_occupancy"] == 0 and s["final_queue_depth"] == 0
+    assert s["dispatches_per_decode_tick"] <= 1.0
+    entries = eng.jit_cache_entries()
+    assert entries["macro_decode"] == 1, entries
+    assert sum(v for v in entries.values() if v > 0) <= 24, entries
     for rid, st in eng.metrics.per_request.items():
         assert st.finish_reason in ("eos", "length", "fault")
         assert st.retries <= 1
@@ -388,6 +414,32 @@ def test_nan_quarantine_retry_reproduces_stream(setup):
             np.testing.assert_array_equal(outs[rid], base[rid])
     lat = faults.detection_latencies(inj.log, eng.metrics.fault_events)
     assert lat and max(lat) <= 4 * eng.serving.macro_ticks
+
+
+@pytest.mark.chaos
+def test_overload_burst_sheds_and_misses_deadlines(setup):
+    """An all-at-once burst into a half-sized queue under shed_oldest,
+    the last request with an impossible 2-tick deadline and a live request
+    cancelled every 3 macro-steps: some requests are shed, some miss their
+    deadline, every request terminates, and the pool drains."""
+    cfg, params, mesh = setup
+    burst = [dataclasses.replace(r, arrival_time=0.0) for r in _poisson(cfg)]
+    burst[-1] = dataclasses.replace(burst[-1], deadline_ticks=2.0)
+    inj = faults.FaultInjector(seed=419, cancel_every=3 * 8)
+    eng = _engine(cfg, params, mesh, injector=inj, max_len=32,
+                  macro_ticks=8, max_queue=len(burst) // 2,
+                  overload_policy="shed_oldest")
+    for r in burst:
+        eng.submit(r)
+    _, s = eng.run()
+    assert s["shed_rate"] > 0, s["finish_reasons"]
+    assert s["deadline_miss_rate"] > 0, s["finish_reasons"]
+    assert s["requests_terminated"] == len(burst)
+    assert s["final_occupancy"] == 0 and s["final_queue_depth"] == 0
+    assert s["dispatches_per_decode_tick"] <= 1.0
+    entries = eng.jit_cache_entries()
+    assert entries["macro_decode"] == 1, entries
+    assert sum(v for v in entries.values() if v > 0) <= 24, entries
 
 
 @pytest.mark.chaos

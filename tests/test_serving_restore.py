@@ -213,6 +213,7 @@ def test_crash_restore_byte_identical(setup, ring_setup, regime, tmp_path):
     for rid in base:
         np.testing.assert_array_equal(outs[rid], base[rid])
     assert s["tokens_replayed"] > 0
+    assert s["journal_bytes"] > 0
     assert s["final_occupancy"] == 0 and s["final_queue_depth"] == 0
     assert s["final_pages_in_use"] == 0
     # Exactly-once streaming: post-restore callbacks got precisely the

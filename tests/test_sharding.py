@@ -1,6 +1,5 @@
 """Logical-axis sharding rules: divisibility fallback, spec construction,
-activation-constraint context (single-device mesh — the 512-device grid is
-exercised by the dry-run in its own process)."""
+activation-constraint context (single-device mesh)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
